@@ -20,6 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from benchmarks.common import table
 from repro.launch.hlo_analysis import analyze_hlo
@@ -32,7 +33,8 @@ def _require_devices(n: int = 8) -> bool:
 def shuffle_scoping() -> list:
     from functools import partial
     from repro.mapreduce import JOBS, corpus, mesh_mapreduce
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
     spec = JOBS["WC"]
     toks, lens = [], []
     for s in range(8):
@@ -57,18 +59,18 @@ def shuffle_scoping() -> list:
 
 def grad_reduction() -> list:
     from functools import partial
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.sharding.collectives import flat_psum, hierarchical_psum
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     g = jnp.zeros((1024, 64), jnp.float32)
     rows = []
     for name, fn in (("flat all-reduce", flat_psum),
                      ("hierarchical (JoSS reduce placement)",
                       hierarchical_psum)):
-        f = shard_map(partial(fn, data_axis="data", pod_axis="pod"),
-                      mesh=mesh, in_specs=P(), out_specs=P(),
-                      check_rep=False)
+        f = jax.shard_map(partial(fn, data_axis="data", pod_axis="pod"),
+                          mesh=mesh, in_specs=P(), out_specs=P(),
+                          check_vma=False)
         txt = jax.jit(f).lower(g).compile().as_text()
         t = analyze_hlo(txt, 8)
         # pod-crossing bytes: collectives whose group spans pods use

@@ -26,7 +26,7 @@ orchestrator itself and asserts its contracts:
     kernel (``repro.sweep.vmap_fill``) is held against real fill
     problems captured from a contended run: the scalar reference is
     **bit-identical** to what the live allocator recorded, the batched
-    kernel is bit-close (``RTOL``) with identical completion orderings,
+    kernel bit-identical to the reference (rates, etas, fronts),
     plus a problems/s microbench of batched vs serial evaluation.
 
 Statistical claims (the paper's Fig. 12 story with error bars, n_seeds
@@ -315,42 +315,32 @@ def run(quick: bool = False, fast: bool = False) -> str:
     out += (f"\n[claim check: scalar fill reference bit-identical to "
             f"the live allocator on {len(snaps)} captured fill "
             "problems]")
-    vmap_row: dict = {"have_jax": vf.HAVE_JAX, "n_snapshots": len(snaps)}
-    if vf.HAVE_JAX:
-        batch = vf.batched_fill(snaps)          # compiles
-        refb = vf.batched_fill_reference(snaps)
-        assert np.allclose(batch["rates"], refb["rates"], rtol=vf.RTOL,
-                           atol=0.0), "batched fill rates out of RTOL"
-        assert np.allclose(batch["dt_next"], refb["dt_next"],
-                           rtol=vf.RTOL, equal_nan=True), \
-            "batched completion fronts out of RTOL"
-        for i in range(len(snaps)):
-            assert vf.orderings_match(refb["etas"][i],
-                                      batch["etas"][i]), \
-                f"completion ordering changed on snapshot {i}"
-        t0 = time.perf_counter()
-        vf.batched_fill(snaps)                   # warm, compiled
-        batched_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        vf.batched_fill_reference(snaps)
-        ref_s = time.perf_counter() - t0
-        vmap_row.update(
-            batched_problems_per_s=len(snaps) / batched_s,
-            ref_problems_per_s=len(snaps) / ref_s,
-            ratio=ref_s / batched_s, rtol=vf.RTOL)
-        out += "\n" + table(
-            "Batched fill kernel — problems/s over the captured corpus "
-            "(vmap over independent fill problems vs the scalar loop)",
-            ["path", "problems", "wall s", "problems/s"],
-            [["vmap (jit, warm)", len(snaps), f"{batched_s:.3f}",
-              f"{len(snaps) / batched_s:.0f}"],
-             ["scalar loop", len(snaps), f"{ref_s:.3f}",
-              f"{len(snaps) / ref_s:.0f}"]])
-        out += (f"\n[claim check: batched kernel bit-close (rtol "
-                f"{vf.RTOL:g}) to the scalar allocator with identical "
-                f"completion orderings on all {len(snaps)} problems]")
-    else:  # pragma: no cover - environment without jax
-        out += "\n(jax unavailable: batched-kernel claims skipped)"
+    vmap_row: dict = {"n_snapshots": len(snaps)}
+    batch = vf.batched_fill(snaps)          # compiles
+    refb = vf.batched_fill_reference(snaps)
+    for key in ("rates", "etas", "dt_next"):
+        assert np.array_equal(batch[key], refb[key]), \
+            f"batched fill {key} differ from the scalar reference"
+    t0 = time.perf_counter()
+    vf.batched_fill(snaps)                   # warm, compiled
+    batched_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    vf.batched_fill_reference(snaps)
+    ref_s = time.perf_counter() - t0
+    vmap_row.update(
+        batched_problems_per_s=len(snaps) / batched_s,
+        ref_problems_per_s=len(snaps) / ref_s,
+        ratio=ref_s / batched_s)
+    out += "\n" + table(
+        "Batched fill kernel — problems/s over the captured corpus "
+        "(vmap over independent fill problems vs the scalar loop)",
+        ["path", "problems", "wall s", "problems/s"],
+        [["vmap (jit, warm)", len(snaps), f"{batched_s:.3f}",
+          f"{len(snaps) / batched_s:.0f}"],
+         ["scalar loop", len(snaps), f"{ref_s:.3f}",
+          f"{len(snaps) / ref_s:.0f}"]])
+    out += (f"\n[claim check: batched kernel bit-identical to the "
+            f"scalar allocator on all {len(snaps)} problems]")
 
     # ------------------------------------------- statistical claim rows --
     rows, gaps = fabric_claims(results)
@@ -463,9 +453,8 @@ def run_lockstep(quick: bool = False, fast: bool = False) -> str:
         scalar runs exactly (completion orderings included: the
         metrics are completion-derived) and the aggregate claim JSON
         is byte-identical;
-      * **degradation** — without jax (``use_jax=False``) the
-        executor's scalar deferred path reproduces the same results
-        bit-for-bit;
+      * **scalar oracle** — the executor's scalar deferred path
+        (``use_jax=False``) reproduces the same results bit-for-bit;
       * **fill throughput** — the batched fill path is >=
         ``MIN_LOCKSTEP_FILL_SPEEDUP`` (3x) faster than the scalar
         allocator's fill path on full runs (half that as a smoke
@@ -477,7 +466,6 @@ def run_lockstep(quick: bool = False, fast: bool = False) -> str:
     preserved), which ``scripts/check_bench_regression.py`` gates.
     """
     from repro.sweep import LockstepExecutor
-    from repro.sweep.vmap_fill import HAVE_JAX
     n_seeds = sweep_seeds(quick or fast)
     write = not (quick or fast)
     specs = lockstep_matrix(n_seeds)
@@ -503,11 +491,11 @@ def run_lockstep(quick: bool = False, fast: bool = False) -> str:
         "lockstep aggregate claim JSON is not byte-identical"
     agg_sha = hashlib.sha256(agg_l.encode()).hexdigest()
 
-    # --------------------------------------- degradation without jax --
+    # ------------------------------------------------ scalar oracle --
     nojax_specs = [s for s in specs if s.seed == 0]
     nojax = LockstepExecutor(use_jax=False).run(nojax_specs)
     assert all(nojax[s.key()] == scalar[s.key()] for s in nojax_specs), \
-        "scalar deferred path (no jax) diverged from the inline runs"
+        "scalar deferred path (use_jax=False) diverged from the inline runs"
 
     # ------------------------------------------------- process pool row --
     n_pool = 2 if (quick or fast) else 4
@@ -537,24 +525,21 @@ def run_lockstep(quick: bool = False, fast: bool = False) -> str:
             f"batches, {st.inline_small} small problems inlined, "
             f"deferred coalescing {coalesce:.2f}x "
             f"({st.problems} problems vs {s_fills} inline solves), "
-            f"used_jax={st.used_jax}]")
+            f"used_jax={st.used_jax}, platform={st.platform}]")
     out += (f"\n[claim check: lockstep bit-identical to scalar on "
             f"{len(specs)} cells (aggregate sha {agg_sha[:12]}...); "
-            f"no-jax deferred path bit-identical on "
+            f"scalar deferred path bit-identical on "
             f"{len(nojax_specs)} cells]")
 
     floor = (MIN_LOCKSTEP_FILL_SPEEDUP if write
              else MIN_LOCKSTEP_FILL_SPEEDUP / 2)
-    if st.used_jax:
-        assert fill_speedup >= floor, \
-            f"lockstep fill path only {fill_speedup:.2f}x the scalar " \
-            f"allocator (need >= {floor:.1f}x)"
-        out += (f"\n[claim check: batched fill path {fill_speedup:.2f}x "
-                f"the scalar allocator (floor {floor:.1f}x)]")
-    else:  # pragma: no cover - environment without jax
-        out += "\n(jax unavailable: fill-throughput gate skipped)"
+    assert fill_speedup >= floor, \
+        f"lockstep fill path only {fill_speedup:.2f}x the scalar " \
+        f"allocator (need >= {floor:.1f}x)"
+    out += (f"\n[claim check: batched fill path {fill_speedup:.2f}x "
+            f"the scalar allocator (floor {floor:.1f}x)]")
 
-    if write and st.used_jax:
+    if write:
         _merge_key(JSON_PATH, "lockstep", {
             "hosts_per_pod": list(LOCKSTEP_HOSTS_PER_POD),
             "n_jobs": LOCKSTEP_N_JOBS, "n_seeds": n_seeds,
@@ -569,8 +554,6 @@ def run_lockstep(quick: bool = False, fast: bool = False) -> str:
             "aggregate_sha256": agg_sha})
         out += (f"\n\n[merged lockstep block into "
                 f"{os.path.basename(JSON_PATH)}]")
-    elif not HAVE_JAX:  # pragma: no cover
-        out += "\n(jax unavailable: lockstep block not written)"
     return out
 
 

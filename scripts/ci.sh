@@ -75,7 +75,7 @@
 #                      batched executor's per-cell metrics bit-identical
 #                      to serial scalar runs at the committed gate
 #                      point (aggregate claim JSON byte-identical), the
-#                      no-jax scalar deferred path bit-identical too,
+#                      scalar deferred oracle bit-identical too,
 #                      and the batched fill path holds the throughput
 #                      smoke floor (full 3x envelope gated on the
 #                      committed BENCH_sweep.json lockstep block by

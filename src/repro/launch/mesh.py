@@ -6,6 +6,7 @@ jax device state (the dry-run needs to set XLA_FLAGS before first init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,10 +17,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Whatever this process has (CPU smoke tests: 1 device)."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return jax.make_mesh((1, n), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

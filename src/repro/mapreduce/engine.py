@@ -15,9 +15,9 @@ from typing import Optional, Sequence, Tuple  # noqa: F401
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.compile_cache import enable_compile_cache
 from repro.mapreduce.jobs import EMPTY, KVBatch, MapReduceSpec
 
 
@@ -63,16 +63,24 @@ def run_map(spec: MapReduceSpec, tokens: jax.Array, lengths: jax.Array,
 
 
 @partial(jax.jit, static_argnums=0)
+def _local_mapreduce(spec: MapReduceSpec, tokens: jax.Array,
+                     lengths: jax.Array
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    kv = run_map(spec, tokens, lengths, 0)
+    k, v, _, n = _sort_reduce(kv.keys, kv.values, kv.nbytes,
+                              combined_bytes=False)
+    return k, v, n
+
+
 def local_mapreduce(spec: MapReduceSpec, tokens: jax.Array,
                     lengths: jax.Array
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Map+combine+reduce of one shard on one device (the test oracle path).
 
-    Returns (unique_keys, counts, n_unique)."""
-    kv = run_map(spec, tokens, lengths, 0)
-    k, v, _, n = _sort_reduce(kv.keys, kv.values, kv.nbytes,
-                              combined_bytes=False)
-    return k, v, n
+    Returns (unique_keys, counts, n_unique): the first n_unique slots hold
+    the distinct keys in ascending order, the rest EMPTY."""
+    enable_compile_cache()
+    return _local_mapreduce(spec, tokens, lengths)
 
 
 @partial(jax.jit, static_argnums=0)
@@ -87,6 +95,7 @@ def measure_fp(spec: MapReduceSpec, shards_tokens: np.ndarray,
                shards_lengths: np.ndarray) -> np.ndarray:
     """Per-shard filtering percentage (paper Figs. 1-2): map-output bytes over
     map-input bytes, for a (n_shards, S) batch of shards."""
+    enable_compile_cache()
     fn = jax.vmap(lambda t, l: _fp_one(spec, t, l))
     return np.asarray(fn(jnp.asarray(shards_tokens),
                          jnp.asarray(shards_lengths)))
@@ -133,6 +142,7 @@ def mesh_mapreduce(spec: MapReduceSpec, tokens, lengths, mesh: Mesh,
     Returns (unique_keys, counts, n_unique, dropped); leading dim = number
     of shard groups.
     """
+    enable_compile_cache()
     shard_axes = tuple(shard_axes) if shard_axes else tuple(shuffle_axes)
     D = int(np.prod([mesh.shape[a] for a in shuffle_axes]))
     n_groups = int(np.prod([mesh.shape[a] for a in shard_axes]))
@@ -166,7 +176,7 @@ def mesh_mapreduce(spec: MapReduceSpec, tokens, lengths, mesh: Mesh,
                                     combined_bytes=False)
         return (uk[None], uv[None], n[None], dropped[None])
 
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(pspec, pspec),
-                   out_specs=(pspec, pspec, pspec, pspec))
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(pspec, pspec),
+                       out_specs=(pspec, pspec, pspec, pspec))
     return fn(tokens, lengths)

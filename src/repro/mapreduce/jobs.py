@@ -122,15 +122,17 @@ def permu_map(tokens, lengths, doc_id) -> KVBatch:
 #: content token ids start here; ids below are web markup ('<page>', ...)
 MARKUP_IDS = 64
 
+#: Grep's default pattern: a fairly common content word (paper runs common
+#: and uncommon patterns; see grep_map_factory for custom patterns)
+GREP_PATTERN = MARKUP_IDS + 2
+
 JOBS: Dict[str, MapReduceSpec] = {
     # PUMA's WC / II emit one record per occurrence (no combiner): FP ~ 1.0+
     "WC": MapReduceSpec("WC", wc_map, 1, combine_in_map=False),
     # SC combines duplicate 3-grams map-side: web boilerplate -> FP < 1
     "SC": MapReduceSpec("SC", sc_map, 1, combine_in_map=True),
     "II": MapReduceSpec("II", ii_map, 1, combine_in_map=False),
-    # default pattern: a fairly common content word (paper runs common and
-    # uncommon patterns; see grep_map_factory for custom patterns)
-    "Grep": MapReduceSpec("Grep", grep_map_factory(MARKUP_IDS + 2), 1,
+    "Grep": MapReduceSpec("Grep", grep_map_factory(GREP_PATTERN), 1,
                           combine_in_map=False),
     "Permu": MapReduceSpec("Permu", permu_map, 3, combine_in_map=False),
 }
@@ -178,3 +180,27 @@ def corpus(kind: str, n_tokens: int, seed: int = 0, vocab: int = 4096
     else:
         raise ValueError(f"unknown corpus kind {kind!r}")
     return tokens, word_len(tokens)
+
+
+#: one HDFS block of job input (the paper's 128 MB blocks,
+#: ``repro.sim.workloads.BLOCK_MB``)
+BLOCK_BYTES = 128 << 20
+#: token slots of one block: the non-web corpus averages ~6.44 B per
+#: token, so 128 MiB fills ~20.8M slots and the rest are padding
+BLOCK_TOKENS = 20 << 20
+
+
+def block(seed: int, n_slots: int = BLOCK_TOKENS,
+          n_bytes: int = BLOCK_BYTES) -> Tuple[np.ndarray, np.ndarray]:
+    """One input block of the non-web corpus: the longest run of tokens
+    whose byte lengths sum to at most ``n_bytes``, padded to ``n_slots``
+    with token -1 and length 0, which every map function skips."""
+    tokens, lengths = corpus("non-web", n_slots, seed=seed)
+    ends = np.cumsum(lengths, dtype=np.int64)
+    if ends[-1] < n_bytes:
+        raise ValueError(f"{n_slots} token slots hold only {ends[-1]} "
+                         f"bytes, short of {n_bytes}")
+    n = int(np.searchsorted(ends, n_bytes, side="right"))
+    tokens[n:] = -1
+    lengths[n:] = 0
+    return tokens, lengths

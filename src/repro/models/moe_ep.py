@@ -31,7 +31,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -149,10 +148,10 @@ def moe_ffn_ep(cfg: ArchConfig, p: Dict[str, jax.Array], x: jax.Array
         return out.reshape(xb.shape), aux
 
     token_spec = P(batch_axes if batch_axes else None, ep_axis)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(token_spec, P(), P(ep_axis), P(ep_axis)),
         out_specs=(token_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["wi"], p["wo"])
     return out, aux

@@ -21,7 +21,6 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -55,10 +54,10 @@ def make_grad_allreduce(mesh: Mesh, *, hierarchical: bool = True):
         def one(g):
             red = partial(fn, data_axis="data", pod_axis=pod_axis)
             spec = P()  # replicated in, replicated out
-            # check_rep=False: the scatter->psum->gather chain's output IS
+            # check_vma=False: the scatter->psum->gather chain's output IS
             # replicated over 'data' but the static checker can't see it
-            return shard_map(red, mesh=mesh, in_specs=spec,
-                             out_specs=spec, check_rep=False)(g)
+            return jax.shard_map(red, mesh=mesh, in_specs=spec,
+                                 out_specs=spec, check_vma=False)(g)
         return jax.tree_util.tree_map(one, grads)
 
     return reduce_tree

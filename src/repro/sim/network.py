@@ -468,8 +468,8 @@ class NetworkFabric(_FabricBase):
         self.fill_backend: Optional[FillBackend] = None
         self._fill_pending = False
         self._pending_now = 0.0
-        # class-structure arrays for fill_problem(): (members, fcap,
-        # cap_rank) depend only on the class *set*. Built on the first
+        # class-structure arrays for fill_problem(): (members, fcap)
+        # depend only on the class *set*. Built on the first
         # fill_problem() call and maintained incrementally at class
         # birth/death from then on (np.insert/np.delete — the class set
         # churns on most fills, so a rebuild-on-dirty cache thrashes).
@@ -516,13 +516,12 @@ class NetworkFabric(_FabricBase):
             members   (C, L)  class-crosses-link incidence (0/1)
             n         (C,)    live members per class
             fcap      (C,)    per-flow rate cap per class
-            cap_rank  (C,)    position in the fill_key (cap, sig) order
             remaining (C,)    earliest front target minus vdone — the
                               ETA numerator (inf when no live front)
 
         Classes appear in sorted-signature order (``self._order``) —
         the order ``apply_fill`` expects rates back in. The
-        members/fcap/cap_rank block is maintained incrementally at
+        members/fcap block is maintained incrementally at
         class birth/death (first call builds it); n/remaining are
         snapshotted per problem, and caps whenever capacities move.
         remaining lets the batched kernel return ``dt_next`` alongside
@@ -531,7 +530,7 @@ class NetworkFabric(_FabricBase):
         tombstone pops, just earlier in the barrier)."""
         if self._struct_arrays is None:
             self._build_struct()
-        members, fcap, cap_rank = self._struct_arrays
+        members, fcap = self._struct_arrays
         order = self._order
         C = len(order)
         n = np.fromiter((c.n for c in order), float, C)
@@ -555,11 +554,10 @@ class NetworkFabric(_FabricBase):
         # happens between the barrier's collect and its delivery)
         self._pending_n = n
         return {"caps": self._caps_arr, "members": members, "n": n,
-                "fcap": fcap, "cap_rank": cap_rank,
-                "remaining": remaining}
+                "fcap": fcap, "remaining": remaining}
 
     def _build_struct(self) -> None:
-        """Full (members, fcap, cap_rank) build — runs once, on the
+        """Full (members, fcap) build — runs once, on the
         first ``fill_problem``; class birth/death maintains the arrays
         incrementally from then on (``_add_class``/``_drop_class``)."""
         order = self._order
@@ -573,11 +571,7 @@ class NetworkFabric(_FabricBase):
             row = members[j]
             for link in cls.path:
                 row[idx[link]] = 1.0
-        cap_rank = np.empty(C)
-        pos = {cls.sig: j for j, cls in enumerate(order)}
-        for rank, cls in enumerate(self._cap_order):
-            cap_rank[pos[cls.sig]] = rank
-        self._struct_arrays = (members, fcap, cap_rank)
+        self._struct_arrays = (members, fcap)
 
     def apply_fill(self, rates, dt_next: Optional[float] = None) -> None:
         """Deliver a deferred fill's solution: ``rates[j]`` is the
@@ -631,7 +625,7 @@ class NetworkFabric(_FabricBase):
         """Deliver a deferred fill with the fabric's own scalar
         recompute — the backend-installed path degrades to exactly the
         inline allocator (used by :class:`InlineFillBackend` and the
-        lockstep executor's no-jax fallback)."""
+        lockstep executor's scalar oracle, ``use_jax=False``)."""
         if not self._fill_pending:
             raise RuntimeError("solve_fill_inline with no fill pending")
         self._recompute()
@@ -653,10 +647,9 @@ class NetworkFabric(_FabricBase):
         arrs = self._struct_arrays
         if arrs is not None:
             # incremental maintenance of the fill_problem arrays: the
-            # new class lands at order position i / cap rank j, pushing
-            # existing ranks >= j up by one. Hand-rolled slice copies —
-            # np.insert's python wrapper costs ~10x the memcpy.
-            members, fcap, cap_rank = arrs
+            # new class lands at order position i. Hand-rolled slice
+            # copies — np.insert's python wrapper costs ~10x the memcpy.
+            members, fcap = arrs
             C, L = members.shape
             m2 = np.zeros((C + 1, L))
             m2[:i] = members[:i]
@@ -669,13 +662,7 @@ class NetworkFabric(_FabricBase):
             f2[:i] = fcap[:i]
             f2[i] = cls.cap
             f2[i + 1:] = fcap[i:]
-            r2 = np.empty(C + 1)
-            r2[:i] = cap_rank[:i]
-            r2[i] = j
-            r2[i + 1:] = cap_rank[i:]
-            r2[r2 >= j] += 1.0
-            r2[i] = j
-            self._struct_arrays = (m2, f2, r2)
+            self._struct_arrays = (m2, f2)
             self._pending_n = None
         return cls
 
@@ -691,7 +678,7 @@ class NetworkFabric(_FabricBase):
             self._users[link].remove(cls)
         arrs = self._struct_arrays
         if arrs is not None:
-            members, fcap, cap_rank = arrs
+            members, fcap = arrs
             C, L = members.shape
             m2 = np.empty((C - 1, L))
             m2[:i] = members[:i]
@@ -699,11 +686,7 @@ class NetworkFabric(_FabricBase):
             f2 = np.empty(C - 1)
             f2[:i] = fcap[:i]
             f2[i:] = fcap[i + 1:]
-            r2 = np.empty(C - 1)
-            r2[:i] = cap_rank[:i]
-            r2[i:] = cap_rank[i + 1:]
-            r2[r2 > j] -= 1.0
-            self._struct_arrays = (m2, f2, r2)
+            self._struct_arrays = (m2, f2)
             self._pending_n = None
 
     # -- flow API ----------------------------------------------------------------

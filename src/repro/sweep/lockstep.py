@@ -29,25 +29,26 @@ between barriers, one fill problem per lane per epoch. A dynamic gang
 (default 64 lanes) refills from the cell queue as lanes retire, keeping
 batches full for the whole matrix.
 
-Correctness contract (tests/test_lockstep.py): per-cell metrics
-bit-close (rtol ``vmap_fill.RTOL``) to scalar ``run_cell`` runs with
-identical completion orderings, and byte-identical aggregate claim
-JSON. The kernel is in fact bit-*identical* to the scalar allocator on
-this XLA build, and the executor asserts nothing weaker — equality is
-checked downstream, not here. Without jax the executor degrades to
-``solve_fill_inline`` per lane (same deferred protocol, scalar solve),
-which is arithmetic-identical to the inline path by construction.
+Correctness contract (tests/test_lockstep.py): per-cell metrics equal
+to scalar ``run_cell`` runs under ``==`` and byte-identical aggregate
+claim JSON — equality is checked downstream, not here. The kernel always
+runs; a JAX that cannot load raises. ``use_jax=False`` is the scalar
+test oracle: ``solve_fill_inline`` per lane (same deferred protocol,
+scalar solve), arithmetic-identical to the inline path by construction.
+
+This module imports JAX only when a solver is built, so sweep pool
+workers, which import ``repro.sweep`` to run cells, never load it and
+never contend for the parent's accelerator.
 """
 from __future__ import annotations
 
 import dataclasses
 import gc
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Sequence
 
 from repro.sim.network import FillBackend
 from repro.sweep.cells import LOCKSTEP_BUILDERS, CellSpec, run_cell
-from repro.sweep.vmap_fill import HAVE_JAX
 
 MetricRow = Dict[str, float]
 
@@ -81,6 +82,7 @@ class LockstepStats:
     fill_s: float = 0.0   # wall seconds in the batched fill path
     wall_s: float = 0.0
     used_jax: bool = False
+    platform: str = ""    # device platform the kernel's outputs were on
 
 
 class _Lane:
@@ -133,13 +135,12 @@ class _Lane:
 class LockstepExecutor:
     """Drives a cell list through the lockstep protocol. ``gang_size``
     bounds concurrent lanes (memory: each lane is a full simulator);
-    ``use_jax=None`` auto-detects, ``False`` forces the scalar
-    deferred path (used by equivalence tests)."""
+    ``use_jax=False`` forces the scalar deferred path, the oracle of the
+    equivalence tests."""
 
-    def __init__(self, *, gang_size: int = 64,
-                 use_jax: Optional[bool] = None):
+    def __init__(self, *, gang_size: int = 64, use_jax: bool = True):
         self.gang_size = max(1, int(gang_size))
-        self.use_jax = HAVE_JAX if use_jax is None else bool(use_jax)
+        self.use_jax = bool(use_jax)
         self.stats = LockstepStats()
 
     def run(self, specs: Sequence[CellSpec]) -> Dict[str, MetricRow]:
@@ -181,6 +182,7 @@ class LockstepExecutor:
             gc.collect()
             if solver is not None:
                 st.batches = solver.n_batches
+                st.platform = solver.platform
                 solver.close()
         st.wall_s = time.perf_counter() - t0
         return {k: results[k] for k in sorted(results)}

@@ -18,13 +18,14 @@ twin (the same pattern as ``network_reference``):
     arithmetic operation-for-operation. Equivalence tests hold it
     **bit-identical** to the rates the live allocator recorded.
   * :func:`batched_fill` — the same algorithm as a jitted
-    ``vmap(lax.while_loop)`` over a padded batch, in float64
-    (``jax.experimental.enable_x64``). XLA's fused multiply-adds round
-    the debit step differently from CPython, so the contract vs the
-    scalar path is *bit-close* (<= a few ulp; ``RTOL``), with completion
-    orderings identical — asserted by ``tests/test_sweep_vmap.py`` and
-    the bench_sweep claim checks over real contention-sweep snapshots
-    (captured via ``FabricConfig.capture_fills``).
+    ``vmap(lax.while_loop)`` over a padded batch. Every float64 travels
+    as its bit pattern and the kernel's arithmetic is integer-only IEEE
+    (:mod:`repro.sweep.exact_f64`), because a TPU emulates f64 with
+    pairs of f32 and rounds differently. The kernel is held
+    **bit-identical** to the scalar path — rates, per-class etas and
+    ``dt_next`` — by ``tests/test_sweep_vmap.py`` and the bench_sweep
+    claim checks over real contention-sweep snapshots (captured via
+    ``FabricConfig.capture_fills``).
 
 Problems come as the snapshot dicts ``NetworkFabric`` records:
 
@@ -39,24 +40,15 @@ ground truth the kernels are held against.
 from __future__ import annotations
 
 import functools
-import os
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import enable_x64, lax
 
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-    HAVE_JAX = True
-except Exception:  # pragma: no cover - environment without jax
-    HAVE_JAX = False
-
-#: relative tolerance of the bit-close contract between the batched
-#: kernel and the scalar allocator (float64; the only divergence source
-#: is XLA FMA fusion in the debit step, a few ulp per round)
-RTOL = 1e-9
+from repro.compile_cache import enable_compile_cache
+from repro.sweep import exact_f64 as xf
 
 _INF = float("inf")
 
@@ -133,8 +125,8 @@ class PackedProblems:
     both kernels consume. Padded links carry zero members and +inf
     capacity; padded classes have n=0 and start pre-fixed."""
 
-    __slots__ = ("caps", "members", "n", "fcap", "cap_rank", "vdone",
-                 "target", "n_classes", "n_links")
+    __slots__ = ("caps", "members", "n", "fcap", "vdone", "target",
+                 "n_classes", "n_links")
 
     def __init__(self, snapshots: Sequence[dict]):
         S = len(snapshots)
@@ -149,7 +141,6 @@ class PackedProblems:
         self.members = np.zeros((S, C, L))
         self.n = np.zeros((S, C))
         self.fcap = np.full((S, C), _INF)
-        self.cap_rank = np.full((S, C), C, dtype=float)
         self.vdone = np.zeros((S, C))
         self.target = np.full((S, C), _INF)
         for si, snap in enumerate(snapshots):
@@ -157,164 +148,124 @@ class PackedProblems:
             for li, (tag, idx, cap) in enumerate(snap["links"]):
                 link_idx[(tag, idx)] = li
                 self.caps[si, li] = cap
-            paths = []
             for cj, c in enumerate(snap["classes"]):
-                path = tuple((tag, idx) for tag, idx in c["path"])
-                paths.append(path)
-                for link in path:
+                for link in ((tag, idx) for tag, idx in c["path"]):
                     self.members[si, cj, link_idx[link]] = 1.0
                 self.n[si, cj] = c["n"]
                 self.fcap[si, cj] = c["cap"]
                 self.vdone[si, cj] = c["vdone"]
                 if c["target"] is not None:
                     self.target[si, cj] = c["target"]
-            order = sorted(range(len(paths)),
-                           key=lambda i: (snap["classes"][i]["cap"],
-                                          (paths[i],
-                                           snap["classes"][i]["cap"])))
-            for rank, i in enumerate(order):
-                self.cap_rank[si, i] = rank
 
 
 # ------------------------------------------------------- jax kernel ---
-if HAVE_JAX:
+def _fill_one(caps, members, n, fcap):
+    """One progressive fill as dense arithmetic, on float64 bit patterns
+    (``exact_f64``): ``caps`` (L,) and ``fcap`` (C,) are uint64
+    patterns, ``members`` (C, L) bool and ``n`` (C,) int32 member
+    counts; returns the (C,) rate patterns. Links are indexed in
+    sorted-link-key order, so ``argmin``'s first-minimum rule IS the
+    allocator's lexicographic ``(share, link_key)`` tie-break; class
+    caps lose exact ties against real links (strict ``<``), mirroring
+    the ``("~cap", sig)`` sentinel sort.
 
-    def _fill_one(caps, members, n, fcap, cap_rank):
-        """One progressive fill as dense arithmetic. Links are indexed
-        in sorted-link-key order, so ``argmin``'s first-minimum rule IS
-        the allocator's lexicographic ``(share, link_key)`` tie-break;
-        class caps lose exact ties against real links (strict ``<``),
-        mirroring the ``("~cap", sig)`` sentinel sort.
+    Two deviations from the literal scalar loop, both provably
+    bit-identical:
 
-        Two deviations from the literal scalar loop, both provably
-        bit-identical:
+    * a cap win fixes **every** unfixed class whose cap equals the
+      winning ``cap_min`` at once, not one per round. The scalar
+      allocator fixes them on consecutive rounds — in between, the
+      links those classes cross keep ``rem/nuse > cap`` (debiting
+      ``k`` members at rate ``cap`` preserves the inequality), so
+      no link can snatch a round in the middle; and the combined
+      debit equals the sequential ones exactly
+      (``max(0, rem - (k1+k2)r)`` == two chained ``max(0, .-kr)``
+      steps, including when the clamp engages). Collapsing the
+      rounds turns uncontended problems from O(C) iterations into
+      O(distinct caps).
+    * per-link member counts are carried in the loop state as
+      integers and debited instead of recomputed each round.
+    """
+    C = members.shape[0]
+    inf = jnp.asarray(xf.INF, xf.U64)
+    fixed = n <= 0            # padded classes never participate
+    rates = jnp.zeros((C,), xf.U64)
+    nuse0 = jnp.sum(jnp.where(members, n[:, None], 0), axis=0)
 
-        * a cap win fixes **every** unfixed class whose cap equals the
-          winning ``cap_min`` at once, not one per round. The scalar
-          allocator fixes them on consecutive rounds — in between, the
-          links those classes cross keep ``rem/nuse > cap`` (debiting
-          ``k`` members at rate ``cap`` preserves the inequality), so
-          no link can snatch a round in the middle; and the combined
-          debit equals the sequential ones exactly
-          (``max(0, rem - (k1+k2)r)`` == two chained ``max(0, .-kr)``
-          steps, including when the clamp engages). Collapsing the
-          rounds turns uncontended problems from O(C) iterations into
-          O(distinct caps). ``cap_rank`` is kept in the signature for
-          packing compatibility but no longer consulted.
-        * per-link member counts are carried in the loop state and
-          debited (exact small-integer float arithmetic) instead of
-          recomputed by a matmul each round.
-        """
-        C = members.shape[0]
-        fixed = n <= 0.0          # padded classes never participate
-        rem = caps
-        rates = jnp.zeros((C,), caps.dtype)
-        nuse0 = n @ members       # exact integer sums
+    def cond(state):
+        fixed, _, _, _ = state
+        return jnp.any(~fixed)
 
-        def cond(state):
-            fixed, _, _, _ = state
-            return jnp.any(~fixed)
+    def body(state):
+        fixed, rem, rates, nuse = state
+        share_l = jnp.where(nuse > 0, xf.div(rem, xf.from_int(nuse)),
+                            inf)
+        li = jnp.argmin(share_l)             # first min = key order
+        link_share = share_l[li]
+        cap_key = jnp.where(~fixed, fcap, inf)
+        cap_min = jnp.min(cap_key)
+        cap_wins = cap_min < link_share
+        share = jnp.where(cap_wins, cap_min, link_share)
+        newly = jnp.where(cap_wins, cap_key == cap_min,
+                          (~fixed) & members[:, li])
+        rates = jnp.where(newly, share, rates)
+        fixed = fixed | newly
+        k_l = jnp.sum(jnp.where(members & newly[:, None], n[:, None], 0),
+                      axis=0)
+        # rem = max(0, rem - k * share), as the scalar allocator rounds
+        # it: the product first, then the difference
+        debit = xf.mul(xf.from_int(k_l), share)
+        rem = jnp.where(k_l > 0,
+                        jnp.where(debit >= rem, jnp.zeros_like(rem),
+                                  xf.sub(rem, debit)), rem)
+        return fixed, rem, rates, nuse - k_l
 
-        def body(state):
-            fixed, rem, rates, nuse = state
-            share_l = jnp.where(nuse > 0.0, rem / nuse, jnp.inf)
-            li = jnp.argmin(share_l)             # first min = key order
-            link_share = share_l[li]
-            cap_key = jnp.where(~fixed, fcap, jnp.inf)
-            cap_min = jnp.min(cap_key)
-            cap_wins = cap_min < link_share
-            share = jnp.where(cap_wins, cap_min, link_share)
-            newly = jnp.where(cap_wins, cap_key == cap_min,
-                              (~fixed) & (members[:, li] > 0.0))
-            rates = jnp.where(newly, share, rates)
-            fixed = fixed | newly
-            k_l = jnp.where(newly, n, 0.0) @ members
-            rem = jnp.where(k_l > 0.0,
-                            jnp.maximum(0.0, rem - k_l * share), rem)
-            return fixed, rem, rates, nuse - k_l
+    _, _, rates, _ = lax.while_loop(cond, body,
+                                    (fixed, caps, rates, nuse0))
+    return rates
 
-        _, _, rates, _ = lax.while_loop(cond, body,
-                                        (fixed, rem, rates, nuse0))
-        return rates
 
-    @functools.lru_cache(maxsize=None)
-    def _jitted_batch():
-        def batch(caps, members, n, fcap, cap_rank, vdone, target):
-            rates = jax.vmap(_fill_one)(caps, members, n, fcap,
-                                        cap_rank)
-            live = (rates > 0.0) & jnp.isfinite(target)
-            etas = jnp.where(live, (target - vdone) / rates, jnp.inf)
-            return rates, etas, jnp.min(etas, axis=1)
-        return jax.jit(batch)
+@functools.lru_cache(maxsize=None)
+def _jitted_fill():
+    """The one jitted entry: a vmapped fill over a padded batch of
+    float64 arrays passed as their uint64 bit patterns (caps (S, L),
+    members (S, C, L), n, fcap and remaining (S, C); ``remaining`` is
+    each class's front ``target - vdone``, subtracted host-side, inf
+    where no front is armed). Returns the patterns of the rates and
+    per-class etas (S, C) and of ``dt_next`` (S,), the earliest eta.
+    ``min`` over etas is exact and ``now + min(etas) == min(now +
+    eta_i)`` (addition of a common term is monotone), so a rearm from
+    ``dt_next`` is bit-identical to the scalar ``_arm`` scan."""
+    def one(caps, members, n, fcap, remaining):
+        rates = _fill_one(caps, members != 0, xf.to_int(n), fcap)
+        finite = (remaining & ((1 << 63) - 1)) < xf.INF
+        live = (rates != 0) & finite
+        etas = jnp.where(live, xf.signed_div(remaining, rates),
+                         jnp.asarray(xf.INF, xf.U64))
+        dt = xf.from_order_key(jnp.min(xf.order_key(etas)))
+        return rates, etas, dt
+    return jax.jit(jax.vmap(one))
 
-    @functools.lru_cache(maxsize=None)
-    def _jitted_rates():
-        """Rates-only jitted vmap (equivalence tests and the
-        rates-only solver path)."""
-        return jax.jit(jax.vmap(_fill_one))
 
-    @functools.lru_cache(maxsize=None)
-    def _jitted_rates_dt():
-        """The lockstep hot path: rates plus the seconds to the
-        earliest completion, so ``apply_fill`` can rearm without its
-        per-class Python loop. ``remaining`` is ``target - vdone``,
-        subtracted host-side (same IEEE op either way; one fewer
-        array to pack and transfer per call). ``min`` over etas is
-        exact and ``now + min(etas) == min(now + eta_i)`` (addition
-        of a common term is monotone), so the armed time is
-        bit-identical to the scalar ``_arm`` scan."""
-        def one(caps, members, n, fcap, cap_rank, remaining):
-            rates = _fill_one(caps, members, n, fcap, cap_rank)
-            live = (rates > 0.0) & jnp.isfinite(remaining)
-            etas = jnp.where(live, remaining / rates, jnp.inf)
-            return rates, jnp.min(etas)
-        return jax.jit(jax.vmap(one))
+def _bits(a: np.ndarray) -> np.ndarray:
+    return a.view(np.uint64)
 
 
 def batched_fill(snapshots: Sequence[dict]) -> dict:
     """Evaluate a batch of fill problems on the jax kernel. Returns
     ``{"rates": (S, C), "etas": (S, C), "dt_next": (S,)}`` numpy
-    float64 arrays (padded lanes hold rate 0 / eta inf); raises
-    ``RuntimeError`` without jax (callers gate on :data:`HAVE_JAX`)."""
-    if not HAVE_JAX:
-        raise RuntimeError("jax is unavailable; use fill_reference")
+    float64 arrays (padded lanes hold rate 0 / eta inf)."""
+    enable_compile_cache()
     p = PackedProblems(snapshots)
-    with enable_x64():
-        rates, etas, dt = _jitted_batch()(
-            p.caps, p.members, p.n, p.fcap, p.cap_rank, p.vdone,
-            p.target)
-        return {"rates": np.asarray(rates), "etas": np.asarray(etas),
-                "dt_next": np.asarray(dt)}
+    remaining = p.target - p.vdone
+    with enable_x64(True):
+        out = _jitted_fill()(_bits(p.caps), _bits(p.members), _bits(p.n),
+                             _bits(p.fcap), _bits(remaining))
+        rates, etas, dt = (np.asarray(x).view(np.float64) for x in out)
+    return {"rates": rates, "etas": etas, "dt_next": dt}
 
 
 # ------------------------------------------------------ live solver ---
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))))
-_CACHE_READY = False
-
-
-def _enable_persistent_cache() -> None:
-    """Point jax's persistent compilation cache at ``.jax_cache`` in the
-    repo root (override: ``REPRO_JAX_CACHE``), so the lockstep kernel's
-    cold-start compile is paid once per machine, not once per process.
-    Best-effort: any failure (unsupported jax, read-only checkout)
-    leaves the in-memory jit cache as the only one."""
-    global _CACHE_READY
-    if _CACHE_READY or not HAVE_JAX:
-        return
-    _CACHE_READY = True
-    try:  # pragma: no cover - depends on jax build/config support
-        cache_dir = (os.environ.get("REPRO_JAX_CACHE")
-                     or os.path.join(_REPO_ROOT, ".jax_cache"))
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-    except Exception:
-        pass
-
-
 def _next_pow2(x: int) -> int:
     """Smallest power of two >= max(1, x) — the shape-bucketing grid."""
     return 1 << max(0, (x - 1).bit_length())
@@ -353,18 +304,17 @@ class BatchedFillSolver:
 
     def __init__(self, *, pad_batch: int = 64, pad_classes: int = 48,
                  pad_links: int = 24):
-        if not HAVE_JAX:
-            raise RuntimeError(
-                "jax is unavailable; use the fabric's inline fill")
         self.pad_batch = _next_pow2(pad_batch)
         self.pad_classes = max(1, int(pad_classes))
         self.pad_links = max(1, int(pad_links))
-        _enable_persistent_cache()
-        self._x64 = enable_x64()
+        enable_compile_cache()
+        self._x64 = enable_x64(True)
         self._x64.__enter__()
         self._open = True
         self.n_batches = 0
         self.n_problems = 0
+        #: platform of the devices the last batch's outputs were on
+        self.platform = ""
         # reusable pack buffers for the (almost always unique) padded
         # shape; {shape: arrays} plus the dirty-row count to reset.
         # Only the latest shape is retained.
@@ -426,10 +376,9 @@ class BatchedFillSolver:
                     np.zeros((PS, PC, PL)),         # members
                     np.zeros((PS, PC)),             # n
                     np.full((PS, PC), _INF),        # fcap
-                    np.full((PS, PC), float(PC)),   # cap_rank
                     np.full((PS, PC), _INF))        # remaining
             self._bufs = {(PS, PC, PL): bufs}
-        caps, members, n, fcap, cap_rank, remaining = bufs
+        caps, members, n, fcap, remaining = bufs
         # restore the pad values the previous call's problems overwrote
         # (rows dirty up to the previous real-lane count). Reuse beats
         # fresh np.full/np.zeros per call: the reset touches S_prev
@@ -440,7 +389,6 @@ class BatchedFillSolver:
             members[:dirty] = 0.0
             n[:dirty] = 0.0
             fcap[:dirty] = _INF
-            cap_rank[:dirty] = float(PC)
             remaining[:dirty] = _INF
         self._dirty_rows = {(PS, PC, PL): S}
         for si, p in enumerate(problems):
@@ -450,12 +398,11 @@ class BatchedFillSolver:
             members[si, :C, :L] = p["members"]
             n[si, :C] = p["n"]
             fcap[si, :C] = p["fcap"]
-            cap_rank[si, :C] = p["cap_rank"]
             remaining[si, :C] = p["remaining"]
-        rates, dts = _jitted_rates_dt()(caps, members, n, fcap,
-                                        cap_rank, remaining)
-        rates = np.asarray(rates)
-        dts = np.asarray(dts)
+        rates, _, dts = _jitted_fill()(*(_bits(b) for b in bufs))
+        self.platform = rates.device.platform
+        rates = np.asarray(rates).view(np.float64)
+        dts = np.asarray(dts).view(np.float64)
         out: List[Tuple[np.ndarray, float]] = [
             (rates[si, :problems[si]["n"].shape[0]], float(dts[si]))
             for si in range(S)]
@@ -466,8 +413,7 @@ class BatchedFillSolver:
 
 def batched_fill_reference(snapshots: Sequence[dict]) -> dict:
     """The pure-Python loop in the batched API shape — the serial
-    baseline of the kernel microbench and the fallback when jax is
-    missing."""
+    baseline of the kernel microbench."""
     S = len(snapshots)
     C = max(1, max((len(s["classes"]) for s in snapshots), default=0))
     rates = np.zeros((S, C))
@@ -519,26 +465,3 @@ def contention_snapshots(algo: str = "joss-t",
     sim = Simulator(cluster, algorithm, jobs, config=cfg, seed=seed)
     sim.run()
     return sim.fabric.fill_snapshots
-
-
-def orderings_match(etas_a: np.ndarray, etas_b: np.ndarray,
-                    rtol: float = RTOL) -> bool:
-    """True when two per-class completion-ETA vectors imply the same
-    completion ordering: finite entries sort identically, with entries
-    closer than ``rtol`` treated as ties (the batched kernel may move a
-    value a few ulp, which must never count as a reordering)."""
-    a = np.asarray(etas_a, dtype=float)
-    b = np.asarray(etas_b, dtype=float)
-    if a.shape != b.shape or not np.array_equal(np.isfinite(a),
-                                                np.isfinite(b)):
-        return False
-    idx = np.where(np.isfinite(a))[0]
-    order_a = sorted(idx, key=lambda i: (a[i], i))
-    order_b = sorted(idx, key=lambda i: (b[i], i))
-    for ia, ib in zip(order_a, order_b):
-        if ia == ib:
-            continue
-        # a swap is only legal between near-equal ETAs (a tie)
-        if not np.isclose(a[ia], a[ib], rtol=rtol, atol=0.0):
-            return False
-    return True

@@ -3,7 +3,8 @@ driven in synchronized epochs with their fabric fills solved through
 the batched vmap kernel must be **bit-identical** — not bit-close — to
 the scalar ``run_cell`` path: same per-cell metric dicts (completion
 orderings included; the metrics are completion-derived), same
-aggregate claim JSON bytes, under any gang size, with and without jax.
+aggregate claim JSON bytes, under any gang size, on the kernel and on
+the scalar oracle (``use_jax=False``).
 The deferred-fill protocol itself is exercised at both ends: the
 inline backend as the equivalence anchor, and the settle guard that
 refuses to advance time across an undelivered fill."""
@@ -14,9 +15,6 @@ from repro.sweep import (LockstepExecutor, ResultStore, SweepEngine,
                          aggregate_json, matrix, run_cell)
 from repro.sweep.cells import build_fabric_contention
 from repro.sweep.lockstep import DeferredFillBackend
-from repro.sweep.vmap_fill import HAVE_JAX
-
-needs_jax = pytest.mark.skipif(not HAVE_JAX, reason="jax unavailable")
 
 #: the bench gate operating point (8 pods x 8 hosts, 24 jobs): fills
 #: span enough classes that the batched kernel actually engages — at
@@ -139,7 +137,6 @@ def test_executor_falls_back_on_unbatchable_family(scalar_results):
 
 
 # --------------------------------------------------- executor (jax) --
-@needs_jax
 def test_executor_batched_path_bit_identical(scalar_results):
     """The tentpole contract: metrics equal the scalar runs exactly
     and the aggregate claim JSON is byte-identical."""
@@ -151,7 +148,6 @@ def test_executor_batched_path_bit_identical(scalar_results):
             == aggregate_json(scalar_results))   # byte-identical
 
 
-@needs_jax
 def test_gang_size_never_changes_results(scalar_results):
     """Batch composition is an implementation detail: a gang of 2
     (many small batches, heavy refill churn) and a gang of 64 (one
@@ -161,7 +157,6 @@ def test_gang_size_never_changes_results(scalar_results):
     assert small == large == scalar_results
 
 
-@needs_jax
 def test_executor_accounts_batches_and_inlining():
     ex = LockstepExecutor()
     ex.run(_specs(n_seeds=1))
@@ -229,3 +224,25 @@ def test_fills_dropped_zero_when_capture_disabled():
     fabric = _capture_run(capture=0)
     assert fabric.fill_snapshots == []
     assert fabric.summary.fills_dropped == 0
+
+
+# ------------------------------------------- pool workers stay off jax --
+def test_pool_worker_path_never_imports_jax():
+    """A spawned sweep worker imports ``repro.sweep.engine`` and runs
+    ``run_cell``; neither may load JAX, or a worker would contend for the
+    accelerator the lockstep parent holds."""
+    import os
+    import subprocess
+    import sys
+    spec = _specs(n_seeds=1)[0]
+    prog = ("import sys\n"
+            "from repro.sweep.engine import _worker_run\n"
+            f"_worker_run({spec.key()!r})\n"
+            "print(sorted(m for m in sys.modules if m.split('.')[0] == 'jax'))\n")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                         text=True, timeout=300,
+                         env=dict(os.environ, PYTHONPATH=src))
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "[]"

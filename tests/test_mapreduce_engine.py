@@ -7,7 +7,8 @@ import numpy as np
 import pytest
 
 from repro.mapreduce import JOBS, corpus, local_mapreduce, measure_fp
-from repro.mapreduce.jobs import EMPTY, word_len
+from repro.mapreduce.jobs import EMPTY, block, word_len
+from repro.mapreduce.reference import emission, reduce_counts
 
 
 def python_wordcount(tokens):
@@ -73,3 +74,30 @@ def test_word_len_deterministic_and_typed():
     markup = word_len(np.arange(0, 64, dtype=np.int32)).mean()
     content = word_len(np.arange(64, 4096, dtype=np.int32)).mean()
     assert markup > content
+
+
+@pytest.mark.parametrize("kind", ["web", "non-web"])
+@pytest.mark.parametrize("name", sorted(JOBS))
+def test_local_mapreduce_matches_numpy_reference(name, kind):
+    """Every job's keys and counts equal the numpy oracle exactly; the
+    slots past n_unique are empty."""
+    tok, lng = corpus(kind, 4096, seed=5)
+    k, v, n = local_mapreduce(JOBS[name], jnp.asarray(tok),
+                              jnp.asarray(lng))
+    k, v, n = np.asarray(k), np.asarray(v), int(n)
+    keys, counts = reduce_counts(*emission(name, tok))
+    assert n == len(keys) > 0
+    np.testing.assert_array_equal(k[:n], keys)
+    np.testing.assert_array_equal(v[:n].astype(np.int64), counts)
+    assert np.all(k[n:] == EMPTY)
+
+
+def test_block_cuts_at_byte_budget_and_pads():
+    tok, lng = block(3, n_slots=4096, n_bytes=20000)
+    n = int((tok >= 0).sum())
+    assert 0 < n < 4096
+    assert np.all(tok[n:] == -1) and np.all(lng[n:] == 0)
+    assert int(lng.sum()) <= 20000 < int(lng.sum()) + int(
+        word_len(corpus("non-web", 4096, seed=3)[0][n:n + 1])[0])
+    with pytest.raises(ValueError, match="short of"):
+        block(3, n_slots=16, n_bytes=20000)

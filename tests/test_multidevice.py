@@ -29,18 +29,18 @@ def test_hierarchical_psum_equals_flat():
     out = run_sub("""
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.sharding.collectives import hierarchical_psum, flat_psum
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16)
-    h = shard_map(partial(hierarchical_psum, data_axis="data",
+    h = jax.shard_map(partial(hierarchical_psum, data_axis="data",
                           pod_axis="pod"),
                   mesh=mesh, in_specs=P("model"), out_specs=P("model"),
-                  check_rep=False)(x)
-    f = shard_map(partial(flat_psum, data_axis="data", pod_axis="pod"),
+                  check_vma=False)(x)
+    f = jax.shard_map(partial(flat_psum, data_axis="data", pod_axis="pod"),
                   mesh=mesh, in_specs=P("model"), out_specs=P("model"),
-                  check_rep=False)(x)
+                  check_vma=False)(x)
     np.testing.assert_allclose(np.asarray(h), np.asarray(f), rtol=1e-6)
     print("PSUM_OK")
     """)
@@ -51,10 +51,10 @@ def test_two_hop_all_to_all_matches_flat():
     out = run_sub("""
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.sharding.collectives import two_hop_all_to_all
-    mesh = jax.make_mesh((2, 4), ("pod", "model"))
+    mesh = jax.make_mesh((2, 4), ("pod", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     # global input: (8 ranks) x (8 dest-chunks) x payload
     x = jnp.arange(8 * 8 * 3, dtype=jnp.float32).reshape(8, 8, 3)
 
@@ -67,8 +67,8 @@ def test_two_hop_all_to_all_matches_flat():
                                   inner_axis="model")[None]
 
     spec = P(("pod", "model"))
-    a = shard_map(flat, mesh=mesh, in_specs=spec, out_specs=spec)(x)
-    b = shard_map(hier, mesh=mesh, in_specs=spec, out_specs=spec)(x)
+    a = jax.shard_map(flat, mesh=mesh, in_specs=spec, out_specs=spec)(x)
+    b = jax.shard_map(hier, mesh=mesh, in_specs=spec, out_specs=spec)(x)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b))
     print("A2A_OK")
     """)
@@ -78,8 +78,9 @@ def test_two_hop_all_to_all_matches_flat():
 def test_mesh_mapreduce_matches_local():
     out = run_sub("""
     import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
     from repro.mapreduce import JOBS, corpus, local_mapreduce, mesh_mapreduce
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     spec = JOBS["WC"]
     toks, lens = [], []
     for s in range(8):
@@ -108,12 +109,14 @@ def test_tiny_sharded_train_step_executes():
     """Not just lowering: run a real sharded train step on 8 devices."""
     out = run_sub("""
     import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
     from repro.configs import get_config
     from repro.models import build_model
     from repro.models.common import axes_tree, shape_tree
     from repro.sharding import DEFAULT_RULES, tree_shardings, use_rules
     from repro.train import TrainConfig, adamw_init, make_train_step
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = get_config("qwen3-4b").smoke()
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -140,6 +143,7 @@ def test_moe_ep_matches_dense_dispatch():
     out = run_sub("""
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
     from repro.configs import get_config
     from repro.models.moe import moe_ffn
     from repro.models.moe_ep import moe_ffn_ep
@@ -147,7 +151,8 @@ def test_moe_ep_matches_dense_dispatch():
     from repro.sharding import DEFAULT_RULES, use_rules
     from repro.models.moe import moe_specs
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = get_config("dbrx-132b").smoke().scaled(
         n_experts=8, moe_topk=2, capacity_factor=8.0)
     specs = moe_specs(cfg, 1)

@@ -3,7 +3,7 @@ missing mesh axes, ZeRO-1 state axes, and the hint() no-op contract."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.models.common import ParamSpec
 from repro.sharding import (DEFAULT_RULES, Rules, hint, logical_to_spec,
@@ -15,7 +15,8 @@ from repro.train.optimizer import zero1_leaf_axes
 def mesh():
     # 1-device mesh: divisibility is trivially satisfied; semantic checks
     # against multi-axis meshes use a fake mesh-like below.
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 class FakeMesh:
