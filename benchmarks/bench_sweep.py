@@ -535,7 +535,9 @@ def run_lockstep(quick: bool = False, fast: bool = False) -> str:
              else MIN_LOCKSTEP_FILL_SPEEDUP / 2)
     assert fill_speedup >= floor, \
         f"lockstep fill path only {fill_speedup:.2f}x the scalar " \
-        f"allocator (need >= {floor:.1f}x)"
+        f"allocator (need >= {floor:.1f}x; fill s {s_fill:.2f} scalar " \
+        f"vs {st.fill_s:.2f} lockstep over {st.batches} batches, " \
+        f"platform={st.platform})"
     out += (f"\n[claim check: batched fill path {fill_speedup:.2f}x "
             f"the scalar allocator (floor {floor:.1f}x)]")
 

@@ -87,15 +87,6 @@ def from_int(k):
     return jnp.where(k == 0, _u(0), bits)
 
 
-def to_int(x):
-    """The integer a pattern of an integer-valued float64 below 2**53
-    holds (0 for +0)."""
-    e = (x >> 52).astype(jnp.int32)
-    m = (x & _u(_FRAC)) | _u(_HIDDEN)
-    n = _shr(m, jnp.maximum(1075 - e, 0))
-    return jnp.where(x == 0, _u(0), n).astype(jnp.int32)
-
-
 def div(a, b):
     """``a / b`` for ``a >= 0`` (``+inf`` allowed) and finite
     ``b > 0``, by restoring long division of the significands."""

@@ -138,8 +138,8 @@ class PackedProblems:
         self.n_classes = C = max(
             1, max((len(s["classes"]) for s in snapshots), default=0))
         self.caps = np.full((S, L), _INF)
-        self.members = np.zeros((S, C, L))
-        self.n = np.zeros((S, C))
+        self.members = np.zeros((S, C, L), bool)
+        self.n = np.zeros((S, C), np.int32)
         self.fcap = np.full((S, C), _INF)
         self.vdone = np.zeros((S, C))
         self.target = np.full((S, C), _INF)
@@ -150,7 +150,7 @@ class PackedProblems:
                 self.caps[si, li] = cap
             for cj, c in enumerate(snap["classes"]):
                 for link in ((tag, idx) for tag, idx in c["path"]):
-                    self.members[si, cj, link_idx[link]] = 1.0
+                    self.members[si, cj, link_idx[link]] = True
                 self.n[si, cj] = c["n"]
                 self.fcap[si, cj] = c["cap"]
                 self.vdone[si, cj] = c["vdone"]
@@ -227,17 +227,17 @@ def _fill_one(caps, members, n, fcap):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_fill():
-    """The one jitted entry: a vmapped fill over a padded batch of
-    float64 arrays passed as their uint64 bit patterns (caps (S, L),
-    members (S, C, L), n, fcap and remaining (S, C); ``remaining`` is
-    each class's front ``target - vdone``, subtracted host-side, inf
-    where no front is armed). Returns the patterns of the rates and
+    """The one jitted entry: a vmapped fill over a padded batch.
+    ``members`` (S, C, L) is bool and ``n`` (S, C) int32; caps (S, L),
+    fcap and remaining (S, C) are float64 arrays passed as their uint64
+    bit patterns. ``remaining`` is each class's front ``target -
+    vdone``, subtracted host-side, inf where no front is armed. Returns the patterns of the rates and
     per-class etas (S, C) and of ``dt_next`` (S,), the earliest eta.
     ``min`` over etas is exact and ``now + min(etas) == min(now +
     eta_i)`` (addition of a common term is monotone), so a rearm from
     ``dt_next`` is bit-identical to the scalar ``_arm`` scan."""
     def one(caps, members, n, fcap, remaining):
-        rates = _fill_one(caps, members != 0, xf.to_int(n), fcap)
+        rates = _fill_one(caps, members, n, fcap)
         finite = (remaining & ((1 << 63) - 1)) < xf.INF
         live = (rates != 0) & finite
         etas = jnp.where(live, xf.signed_div(remaining, rates),
@@ -259,7 +259,7 @@ def batched_fill(snapshots: Sequence[dict]) -> dict:
     p = PackedProblems(snapshots)
     remaining = p.target - p.vdone
     with enable_x64(True):
-        out = _jitted_fill()(_bits(p.caps), _bits(p.members), _bits(p.n),
+        out = _jitted_fill()(_bits(p.caps), p.members, p.n,
                              _bits(p.fcap), _bits(remaining))
         rates, etas, dt = (np.asarray(x).view(np.float64) for x in out)
     return {"rates": rates, "etas": etas, "dt_next": dt}
@@ -373,8 +373,8 @@ class BatchedFillSolver:
         bufs = self._bufs.get((PS, PC, PL))
         if bufs is None:
             bufs = (np.full((PS, PL), _INF),        # caps
-                    np.zeros((PS, PC, PL)),         # members
-                    np.zeros((PS, PC)),             # n
+                    np.zeros((PS, PC, PL), bool),   # members
+                    np.zeros((PS, PC), np.int32),   # n
                     np.full((PS, PC), _INF),        # fcap
                     np.full((PS, PC), _INF))        # remaining
             self._bufs = {(PS, PC, PL): bufs}
@@ -386,8 +386,8 @@ class BatchedFillSolver:
         dirty = self._dirty_rows.get((PS, PC, PL), 0)
         if dirty:
             caps[:dirty] = _INF
-            members[:dirty] = 0.0
-            n[:dirty] = 0.0
+            members[:dirty] = False
+            n[:dirty] = 0
             fcap[:dirty] = _INF
             remaining[:dirty] = _INF
         self._dirty_rows = {(PS, PC, PL): S}
@@ -399,7 +399,8 @@ class BatchedFillSolver:
             n[si, :C] = p["n"]
             fcap[si, :C] = p["fcap"]
             remaining[si, :C] = p["remaining"]
-        rates, _, dts = _jitted_fill()(*(_bits(b) for b in bufs))
+        rates, _, dts = _jitted_fill()(_bits(caps), members, n,
+                                       _bits(fcap), _bits(remaining))
         self.platform = rates.device.platform
         rates = np.asarray(rates).view(np.float64)
         dts = np.asarray(dts).view(np.float64)
