@@ -6,6 +6,11 @@ profiling, and a mesh path (`mesh_mapreduce`) where the shuffle is a real
 placement decisions select those axes: policy A keeps the shuffle on
 intra-pod axes only; policies B/C let it cross the `pod` axis and pin the
 reduced output's sharding (reduce placement == out_shardings).
+
+The local path names its stages for the profiler: `jax.named_scope`
+`mr.map`, `mr.sort`, `mr.gather` and `mr.segment` (op_name metadata only;
+the compiled programs are otherwise unchanged), and `local_mapreduce`
+opens the host span `mr.dispatch` around its dispatch.
 """
 from __future__ import annotations
 
@@ -34,27 +39,31 @@ def _sort_reduce(keys: jax.Array, values: jax.Array, nbytes: jax.Array,
     per unique key (representative key bytes), else the sum of member bytes.
     """
     n = keys.shape[0]
-    order = jnp.argsort(keys)
-    k = keys[order]
-    v = values[order]
-    b = nbytes[order]
-    first = jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
-    seg = jnp.cumsum(first) - 1
-    vsum = jax.ops.segment_sum(v, seg, num_segments=n)
-    bsum = jax.ops.segment_sum(b, seg, num_segments=n)
-    bfirst = jnp.zeros((n,), b.dtype).at[seg].set(b)  # one kv per unique key
-    ukeys = jnp.full((n,), EMPTY, dtype=k.dtype).at[seg].set(k)
-    valid = ukeys != EMPTY
-    out_bytes = jnp.where(valid, bfirst if combined_bytes else bsum, 0)
-    n_unique = jnp.sum(valid.astype(jnp.int32))
-    return (jnp.where(valid, ukeys, EMPTY),
-            jnp.where(valid, vsum, 0).astype(values.dtype),
-            out_bytes.astype(nbytes.dtype), n_unique)
+    with jax.named_scope("mr.sort"):
+        order = jnp.argsort(keys)
+    with jax.named_scope("mr.gather"):
+        k = keys[order]
+        v = values[order]
+        b = nbytes[order]
+    with jax.named_scope("mr.segment"):
+        first = jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
+        seg = jnp.cumsum(first) - 1
+        vsum = jax.ops.segment_sum(v, seg, num_segments=n)
+        bsum = jax.ops.segment_sum(b, seg, num_segments=n)
+        bfirst = jnp.zeros((n,), b.dtype).at[seg].set(b)  # one kv per key
+        ukeys = jnp.full((n,), EMPTY, dtype=k.dtype).at[seg].set(k)
+        valid = ukeys != EMPTY
+        out_bytes = jnp.where(valid, bfirst if combined_bytes else bsum, 0)
+        n_unique = jnp.sum(valid.astype(jnp.int32))
+        return (jnp.where(valid, ukeys, EMPTY),
+                jnp.where(valid, vsum, 0).astype(values.dtype),
+                out_bytes.astype(nbytes.dtype), n_unique)
 
 
 def run_map(spec: MapReduceSpec, tokens: jax.Array, lengths: jax.Array,
             doc_id) -> KVBatch:
-    kv = spec.map_fn(tokens, lengths, jnp.asarray(doc_id, jnp.int32))
+    with jax.named_scope("mr.map"):
+        kv = spec.map_fn(tokens, lengths, jnp.asarray(doc_id, jnp.int32))
     if spec.combine_in_map:
         k, v, b, _ = _sort_reduce(kv.keys, kv.values, kv.nbytes,
                                   combined_bytes=True)
@@ -79,8 +88,9 @@ def local_mapreduce(spec: MapReduceSpec, tokens: jax.Array,
 
     Returns (unique_keys, counts, n_unique): the first n_unique slots hold
     the distinct keys in ascending order, the rest EMPTY."""
-    enable_compile_cache()
-    return _local_mapreduce(spec, tokens, lengths)
+    with jax.profiler.TraceAnnotation("mr.dispatch", job=spec.name):
+        enable_compile_cache()
+        return _local_mapreduce(spec, tokens, lengths)
 
 
 @partial(jax.jit, static_argnums=0)

@@ -10,6 +10,7 @@ import: only the worker that runs this file loads the TPU compiler. A
 missing or broken TPU compiler (libtpu is pinned in requirements.txt)
 fails these tests; it does not skip them.
 """
+import re
 from functools import partial
 
 import numpy as np
@@ -75,6 +76,35 @@ def test_local_mapreduce_compiles_at_block_shape(one_chip, name):
                                  sharding=one_chip)
     compiled = _local_mapreduce.lower(JOBS[name], block, block).compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("name", ["WC", "SC", "II", "Grep", "Permu"])
+def test_local_mapreduce_names_its_stages(one_chip, name):
+    """Every fusion, sort, gather and scatter of the compiled program that
+    JAX emitted carries exactly one ``mr.*`` stage, and the right one;
+    what XLA makes itself (cumsum's reduce-window pieces) carries none."""
+    block = jax.ShapeDtypeStruct((1 << 12,), jnp.int32, sharding=one_chip)
+    hlo = _local_mapreduce.lower(JOBS[name], block, block).compile(
+        ).as_text()
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    seen = set()
+    for line in entry.splitlines()[1:]:
+        m = re.search(r" (fusion|sort|gather|scatter)\(", line)
+        if not m:
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        if not op or not op.group(1).startswith("jit("):
+            assert m.group(1) == "fusion", line
+            continue
+        scopes = [p for p in op.group(1).split("/") if p.startswith("mr.")]
+        assert len(scopes) == 1, line
+        last = op.group(1).rsplit("/", 1)[-1]
+        want = {"sort": "mr.sort", "gather": "mr.gather",
+                "scatter": "mr.segment", "scatter-add": "mr.segment"}
+        assert scopes[0] == want.get(last, scopes[0]), line
+        seen.add(scopes[0])
+    assert seen == {"mr.map", "mr.sort", "mr.gather", "mr.segment"}
 
 
 @pytest.mark.parametrize("shuffle", [("data",), ("pod", "data")])
