@@ -8,9 +8,9 @@ intra-pod axes only; policies B/C let it cross the `pod` axis and pin the
 reduced output's sharding (reduce placement == out_shardings).
 
 The local path names its stages for the profiler: `jax.named_scope`
-`mr.map`, `mr.sort`, `mr.gather` and `mr.segment` (op_name metadata only;
-the compiled programs are otherwise unchanged), and `local_mapreduce`
-opens the host span `mr.dispatch` around its dispatch.
+`mr.map`, `mr.sort` and `mr.segment` (op_name metadata only; the compiled
+programs are otherwise unchanged), and `local_mapreduce` opens the host
+span `mr.dispatch` around its dispatch.
 """
 from __future__ import annotations
 
@@ -37,14 +37,15 @@ def _sort_reduce(keys: jax.Array, values: jax.Array, nbytes: jax.Array,
 
     combined_bytes=True models a combiner's output size: one serialized kv
     per unique key (representative key bytes), else the sum of member bytes.
+
+    The sort carries values and bytes as its payload, so nothing gathers by a
+    permutation. It need not be stable: the sums are exact and every member
+    of a key has the same byte size.
     """
     n = keys.shape[0]
     with jax.named_scope("mr.sort"):
-        order = jnp.argsort(keys)
-    with jax.named_scope("mr.gather"):
-        k = keys[order]
-        v = values[order]
-        b = nbytes[order]
+        k, v, b = jax.lax.sort((keys, values, nbytes), num_keys=1,
+                               is_stable=False)
     with jax.named_scope("mr.segment"):
         first = jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
         seg = jnp.cumsum(first) - 1

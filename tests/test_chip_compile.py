@@ -80,9 +80,12 @@ def test_local_mapreduce_compiles_at_block_shape(one_chip, name):
 
 @pytest.mark.parametrize("name", ["WC", "SC", "II", "Grep", "Permu"])
 def test_local_mapreduce_names_its_stages(one_chip, name):
-    """Every fusion, sort, gather and scatter of the compiled program that
-    JAX emitted carries exactly one ``mr.*`` stage, and the right one;
-    what XLA makes itself (cumsum's reduce-window pieces) carries none."""
+    """Every fusion, sort and scatter of the compiled program that JAX
+    emitted carries exactly one ``mr.*`` stage, and the right one; what XLA
+    makes itself (cumsum's reduce-window pieces) carries none. The sort
+    carries the values as its payload, so no computation of the program
+    gathers, and each ``mr.sort`` sorts the u32 keys with the s32 values,
+    not with an iota to gather by."""
     block = jax.ShapeDtypeStruct((1 << 12,), jnp.int32, sharding=one_chip)
     hlo = _local_mapreduce.lower(JOBS[name], block, block).compile(
         ).as_text()
@@ -100,11 +103,22 @@ def test_local_mapreduce_names_its_stages(one_chip, name):
         scopes = [p for p in op.group(1).split("/") if p.startswith("mr.")]
         assert len(scopes) == 1, line
         last = op.group(1).rsplit("/", 1)[-1]
-        want = {"sort": "mr.sort", "gather": "mr.gather",
+        want = {"sort": "mr.sort",
                 "scatter": "mr.segment", "scatter-add": "mr.segment"}
         assert scopes[0] == want.get(last, scopes[0]), line
         seen.add(scopes[0])
-    assert seen == {"mr.map", "mr.sort", "mr.gather", "mr.segment"}
+    assert seen == {"mr.map", "mr.sort", "mr.segment"}
+    assert " gather(" not in hlo
+    sorts = [line for line in hlo.splitlines()
+             if " sort(" in line and "/mr.sort/" in line]
+    assert sorts
+    for line in sorts:
+        result, operands = re.search(r"= (.*) sort\(([^)]*)\)",
+                                     line).groups()
+        assert re.findall(r"(\w+)\[\d+\]", result) == ["u32", "s32"], line
+        operands = operands.split(", ")
+        assert len(operands) == 2, line
+        assert not any(o.startswith("%iota") for o in operands), line
 
 
 @pytest.mark.parametrize("shuffle", [("data",), ("pod", "data")])

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.mapreduce import JOBS, corpus, local_mapreduce, measure_fp
+from repro.mapreduce.engine import _sort_reduce
 from repro.mapreduce.jobs import EMPTY, block, word_len
 from repro.mapreduce.reference import emission, reduce_counts
 
@@ -90,6 +91,50 @@ def test_local_mapreduce_matches_numpy_reference(name, kind):
     np.testing.assert_array_equal(k[:n], keys)
     np.testing.assert_array_equal(v[:n].astype(np.int64), counts)
     assert np.all(k[n:] == EMPTY)
+
+
+def _kv_slots(case, n=4096, seed=11):
+    """Map-output slots as the map functions emit them: uint32 keys (EMPTY
+    slots carry value 0 and 0 bytes), one byte size per key."""
+    rng = np.random.default_rng(seed)
+    if case == "all_empty":
+        keys = np.full(n, EMPTY, np.uint32)
+    elif case == "distinct":
+        keys = rng.permutation(np.arange(n, dtype=np.uint32) * 977 + 5)
+    else:  # duplicates, EMPTY slots, and keys just below EMPTY
+        pool = np.concatenate([rng.integers(0, 1 << 31, 200, np.uint32),
+                               EMPTY - np.arange(1, 4, dtype=np.uint32)])
+        keys = rng.choice(pool, n)
+        keys[rng.random(n) < 0.3] = EMPTY
+    valid = keys != EMPTY
+    values = np.where(valid, rng.integers(-5, 100, n), 0).astype(np.int32)
+    nbytes = np.where(valid, keys % 11 + 2, 0).astype(np.int32)
+    return keys, values, nbytes
+
+
+@pytest.mark.parametrize("case", ["mixed", "distinct", "all_empty"])
+@pytest.mark.parametrize("combined_bytes", [True, False])
+def test_sort_reduce_matches_numpy(case, combined_bytes):
+    """Unique keys ascending, each key's summed values and its output bytes
+    (one representative kv, or the members' sum) equal numpy's exactly;
+    slots past n_unique hold EMPTY, 0 and 0."""
+    keys, values, nbytes = _kv_slots(case)
+    k, v, b, n = (np.asarray(a) for a in _sort_reduce(
+        jnp.asarray(keys), jnp.asarray(values), jnp.asarray(nbytes),
+        combined_bytes=combined_bytes))
+    valid = keys != EMPTY
+    want_k, inverse = np.unique(keys[valid], return_inverse=True)
+    want_v = np.bincount(inverse, values[valid], minlength=len(want_k))
+    want_b = (want_k % 11 + 2 if combined_bytes else
+              np.bincount(inverse, nbytes[valid], minlength=len(want_k)))
+    u = len(want_k)
+    assert int(n) == u
+    assert k.dtype == np.uint32 and v.dtype == b.dtype == np.int32
+    np.testing.assert_array_equal(k[:u], want_k)
+    np.testing.assert_array_equal(v[:u], want_v)
+    np.testing.assert_array_equal(b[:u], want_b)
+    assert np.all(k[u:] == EMPTY) and np.all(v[u:] == 0)
+    assert np.all(b[u:] == 0)
 
 
 def test_block_cuts_at_byte_budget_and_pads():
