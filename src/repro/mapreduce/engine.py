@@ -7,10 +7,13 @@ placement decisions select those axes: policy A keeps the shuffle on
 intra-pod axes only; policies B/C let it cross the `pod` axis and pin the
 reduced output's sharding (reduce placement == out_shardings).
 
-The local path names its stages for the profiler: `jax.named_scope`
-`mr.map`, `mr.sort` and `mr.segment` (op_name metadata only; the compiled
-programs are otherwise unchanged), and `local_mapreduce` opens the host
-span `mr.dispatch` around its dispatch.
+Both paths name their stages for the profiler with `jax.named_scope`
+(op_name metadata only; the compiled programs are otherwise unchanged):
+`mr.map`, `mr.sort` and `mr.segment`, and on the mesh path `mr.pack`
+(`_partition_pack`) and `mr.shuffle` (the two all_to_alls). The mesh path
+is one jitted program, `_mesh_mapreduce`, keyed on (spec, mesh, axes,
+slack). `local_mapreduce` and `mesh_mapreduce` open the host span
+`mr.dispatch` around their dispatch.
 """
 from __future__ import annotations
 
@@ -150,20 +153,34 @@ def mesh_mapreduce(spec: MapReduceSpec, tokens, lengths, mesh: Mesh,
     shard_axes=('pod','data') with shuffle_axes=('data',) is JoSS policy A:
     every pod reduces its own shards with ZERO cross-pod shuffle bytes.
 
+    One jitted program, `_mesh_mapreduce`, per (spec, mesh, shuffle_axes,
+    shard_axes, slack): a second call with the same shapes compiles
+    nothing. It opens the host span `mr.dispatch` around its dispatch, as
+    `local_mapreduce` does.
+
     Returns (unique_keys, counts, n_unique, dropped); leading dim = number
     of shard groups.
     """
-    enable_compile_cache()
-    shard_axes = tuple(shard_axes) if shard_axes else tuple(shuffle_axes)
-    D = int(np.prod([mesh.shape[a] for a in shuffle_axes]))
+    shuffle_axes = tuple(shuffle_axes)
+    shard_axes = tuple(shard_axes) if shard_axes else shuffle_axes
     n_groups = int(np.prod([mesh.shape[a] for a in shard_axes]))
-    n_shards, S = tokens.shape
-    if n_shards % n_groups:
+    if tokens.shape[0] % n_groups:
         raise ValueError(
-            f"n_shards {n_shards} not divisible by {n_groups}")
-    cap = S * spec.cap_mult
+            f"n_shards {tokens.shape[0]} not divisible by {n_groups}")
+    with jax.profiler.TraceAnnotation("mr.dispatch", job=spec.name):
+        enable_compile_cache()
+        return _mesh_mapreduce(spec, mesh, shuffle_axes, shard_axes,
+                               int(slack), tokens, lengths)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _mesh_mapreduce(spec: MapReduceSpec, mesh: Mesh,
+                    shuffle_axes: Tuple[str, ...],
+                    shard_axes: Tuple[str, ...], slack: int,
+                    tokens: jax.Array, lengths: jax.Array):
+    D = int(np.prod([mesh.shape[a] for a in shuffle_axes]))
+    cap = tokens.shape[1] * spec.cap_mult
     cap_dest = slack * -(-cap // D)
-    axes = tuple(shuffle_axes)
     pspec = P(shard_axes)
 
     def shard_fn(tok, lng):
@@ -175,12 +192,15 @@ def mesh_mapreduce(spec: MapReduceSpec, tokens, lengths, mesh: Mesh,
         kv = jax.vmap(one)(tok, lng)
         flat = KVBatch(kv.keys.reshape(-1), kv.values.reshape(-1),
                        kv.nbytes.reshape(-1), kv.cap * tok.shape[0])
-        bk, bv, dropped = _partition_pack(flat, D, cap_dest * tok.shape[0])
+        with jax.named_scope("mr.pack"):
+            bk, bv, dropped = _partition_pack(flat, D,
+                                              cap_dest * tok.shape[0])
         # the shuffle: one all_to_all over the chosen axes
-        rk = jax.lax.all_to_all(bk, axes, split_axis=0, concat_axis=0,
-                                tiled=True)
-        rv = jax.lax.all_to_all(bv, axes, split_axis=0, concat_axis=0,
-                                tiled=True)
+        with jax.named_scope("mr.shuffle"):
+            rk = jax.lax.all_to_all(bk, shuffle_axes, split_axis=0,
+                                    concat_axis=0, tiled=True)
+            rv = jax.lax.all_to_all(bv, shuffle_axes, split_axis=0,
+                                    concat_axis=0, tiled=True)
         rk = rk.reshape(-1)
         rv = rv.reshape(-1)
         uk, uv, _, n = _sort_reduce(rk, rv, jnp.zeros_like(rv),

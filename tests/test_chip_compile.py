@@ -3,15 +3,15 @@
 Compiles against a described ``v5e:2x2`` topology, with no chip attached:
 the lockstep fill kernel at its live padded shape (64 x 48 x 24, float64
 bit patterns), ``local_mapreduce`` of WordCount and Permu on one 128 MiB
-block, and ``mesh_mapreduce`` on the 2x2 (pod, data) mesh. Each program
-must fit the chip's 16 GB of HBM. Nothing runs, so this says nothing of
-results or times. The topology is described inside a fixture, never at
+block, and ``mesh_mapreduce``'s jitted program on the 2x2 (pod, data)
+mesh at the benchmark's two blocks per chip. Each program must fit the
+chip's 16 GB of HBM. Nothing runs, so this says nothing of results or
+times. The topology is described inside a fixture, never at
 import: only the worker that runs this file loads the TPU compiler. A
 missing or broken TPU compiler (libtpu is pinned in requirements.txt)
 fails these tests; it does not skip them.
 """
 import re
-from functools import partial
 
 import numpy as np
 import pytest
@@ -21,8 +21,8 @@ import jax.numpy as jnp
 from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.mapreduce import JOBS, mesh_mapreduce
-from repro.mapreduce.engine import _local_mapreduce
+from repro.mapreduce import JOBS
+from repro.mapreduce.engine import _local_mapreduce, _mesh_mapreduce
 from repro.mapreduce.jobs import BLOCK_TOKENS
 from repro.sweep.vmap_fill import _jitted_fill
 
@@ -123,13 +123,26 @@ def test_local_mapreduce_names_its_stages(one_chip, name):
 
 @pytest.mark.parametrize("shuffle", [("data",), ("pod", "data")])
 def test_mesh_mapreduce_compiles_on_2x2(topo, shuffle):
+    """The benchmark's 1 GiB WordCount job, 2 blocks of 20 Mi slots per
+    chip, fits one chip's HBM as the one jitted mesh program; every
+    all-to-all carries ``mr.shuffle``, and the pack's sort and scatters
+    carry ``mr.pack``."""
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("pod", "data"),
                 axis_types=(AxisType.Auto,) * 2)
     blocks = jax.ShapeDtypeStruct(
-        (4, BLOCK_TOKENS), jnp.int32,
+        (8, BLOCK_TOKENS), jnp.int32,
         sharding=NamedSharding(mesh, P(("pod", "data"))))
-    compiled = jax.jit(partial(
-        mesh_mapreduce, JOBS["WC"], mesh=mesh, shuffle_axes=shuffle,
-        shard_axes=("pod", "data"))).lower(blocks, blocks).compile()
+    compiled = _mesh_mapreduce.lower(
+        JOBS["WC"], mesh, shuffle, ("pod", "data"), 4, blocks,
+        blocks).compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES
-    assert "all-to-all" in compiled.as_text()
+    hlo = compiled.as_text()
+    a2a = [line for line in hlo.splitlines()
+           if re.search(r" all-to-all(-start)?\(", line)]
+    assert len(a2a) == 2
+    assert all("/mr.shuffle/" in line for line in a2a), a2a
+    pack = [re.search(r" (sort|scatter)\(", line).group(1)
+            for line in hlo.splitlines()
+            if re.search(r" (sort|scatter)\(", line)
+            and "/mr.pack/" in line]
+    assert "sort" in pack and "scatter" in pack, pack

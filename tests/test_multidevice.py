@@ -1,7 +1,9 @@
 """Multi-device semantics (8 fake CPU devices in a subprocess, because
 device count locks at first jax init): shard_map collectives, the
 hierarchical psum equivalence, the two-hop all_to_all, the mesh
-mapreduce engine, and a tiny sharded train-step lowering."""
+mapreduce engine (and, on 4 devices, the jitted mesh path against the
+benchmark's shuffle reference), and a tiny sharded train-step
+lowering."""
 import os
 import subprocess
 import sys
@@ -12,13 +14,13 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def run_sub(code: str) -> str:
+def run_sub(code: str, devices: int = 8) -> str:
     prog = ("import os\n"
             "os.environ['XLA_FLAGS'] = "
-            "'--xla_force_host_platform_device_count=8'\n" +
+            f"'--xla_force_host_platform_device_count={devices}'\n" +
             textwrap.dedent(code))
-    env = dict(os.environ,
-               PYTHONPATH=os.path.join(REPO, "src"))
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [os.path.join(REPO, "src"), REPO]))
     out = subprocess.run([sys.executable, "-c", prog], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
@@ -103,6 +105,49 @@ def test_mesh_mapreduce_matches_local():
     print("MR_OK")
     """)
     assert "MR_OK" in out
+
+
+@pytest.mark.parametrize("job", ["WC", "SC"])
+@pytest.mark.parametrize("shuffle", [("pod", "data"), ("data",)])
+def test_jitted_mesh_mapreduce_matches_the_shuffle_reference(job, shuffle):
+    """On a 2x2 (pod, data) mesh, 2 blocks of 64 Ki slots per chip: every
+    reducer holds exactly what ``bench/reference/shuffle.py`` says, none
+    drops a record, and a second call compiles and loads nothing."""
+    out = run_sub(f"""
+    import jax, numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+    from bench.corpus import block_key, make_blocks
+    from bench.device import CompileCounter
+    from bench.reference import shuffle
+    from repro.mapreduce import JOBS, mesh_mapreduce
+    mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("pod", "data"),
+                axis_types=(AxisType.Auto,) * 2)
+    corpus = {{"markup_ids": 64, "words": 5000, "zipf_s": 1.0}}
+    tok, lng, _ = make_blocks(
+        [block_key(2 ** 33 + 7, b) for b in range(8)],
+        {{"slots": 1 << 16, "block_bytes": 1 << 18}}, corpus,
+        NamedSharding(mesh, P(("pod", "data"))))
+    args = (JOBS[{job!r}], tok, lng, mesh)
+    kw = dict(shuffle_axes={shuffle!r}, shard_axes=("pod", "data"))
+    uk, uv, n, dropped = jax.block_until_ready(mesh_mapreduce(*args, **kw))
+    counter = CompileCounter()
+    mark = counter.mark()
+    jax.block_until_ready(mesh_mapreduce(*args, **kw))
+    again = counter.since(mark)
+    assert again == {{"compiled": 0, "loaded": 0}}, again
+    assert int(np.asarray(dropped).sum()) == 0
+    lay = shuffle.Layout((2, 2), ("pod", "data"), {shuffle!r}, 2)
+    want = shuffle.reducer_outputs({job!r}, np.asarray(tok), lay)
+    uk, uv, n = np.asarray(uk), np.asarray(uv), np.asarray(n)
+    for g, (wk, wc) in enumerate(want):
+        m = int(n[g])
+        assert m == len(wk) > 0, (g, m, len(wk))
+        assert np.array_equal(uk[g, :m], wk)
+        assert np.array_equal(uv[g, :m].astype(np.int64), wc)
+        assert (uk[g, m:] == 0xFFFFFFFF).all()
+    print("MESH_OK")
+    """, devices=4)
+    assert "MESH_OK" in out
 
 
 def test_tiny_sharded_train_step_executes():
