@@ -44,23 +44,34 @@ def _sort_reduce(keys: jax.Array, values: jax.Array, nbytes: jax.Array,
     The sort carries values and bytes as its payload, so nothing gathers by a
     permutation. It need not be stable: the sums are exact and every member
     of a key has the same byte size.
+
+    Segmenting scatters nothing: after the sort each key is one run, whose
+    sum is the difference of the prefix sums at its last slot and at the
+    previous run's last slot. A second sort brings the run ends, keyed by
+    their key (EMPTY elsewhere), to the front in ascending order with their
+    prefix sums. Integer prefix differences wrap as the sums' own adds do,
+    so every sum is exact modulo 2**32.
     """
-    n = keys.shape[0]
     with jax.named_scope("mr.sort"):
         k, v, b = jax.lax.sort((keys, values, nbytes), num_keys=1,
                                is_stable=False)
     with jax.named_scope("mr.segment"):
-        first = jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
-        seg = jnp.cumsum(first) - 1
-        vsum = jax.ops.segment_sum(v, seg, num_segments=n)
-        bsum = jax.ops.segment_sum(b, seg, num_segments=n)
-        bfirst = jnp.zeros((n,), b.dtype).at[seg].set(b)  # one kv per key
-        ukeys = jnp.full((n,), EMPTY, dtype=k.dtype).at[seg].set(k)
+        last = jnp.concatenate([k[:-1] != k[1:], jnp.ones((1,), bool)])
+        # one kv per key (the run end's bytes), else the members' sum
+        ukeys, cv, cb = jax.lax.sort(
+            (jnp.where(last, k, EMPTY), jnp.cumsum(v, dtype=v.dtype),
+             b if combined_bytes else jnp.cumsum(b, dtype=b.dtype)),
+            num_keys=1, is_stable=False)
+
+        def run_sums(c):
+            return c - jnp.concatenate([jnp.zeros((1,), c.dtype), c[:-1]])
+
         valid = ukeys != EMPTY
-        out_bytes = jnp.where(valid, bfirst if combined_bytes else bsum, 0)
+        out_bytes = jnp.where(valid, cb if combined_bytes else run_sums(cb),
+                              0)
         n_unique = jnp.sum(valid.astype(jnp.int32))
-        return (jnp.where(valid, ukeys, EMPTY),
-                jnp.where(valid, vsum, 0).astype(values.dtype),
+        return (ukeys,
+                jnp.where(valid, run_sums(cv), 0).astype(values.dtype),
                 out_bytes.astype(nbytes.dtype), n_unique)
 
 

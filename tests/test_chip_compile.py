@@ -78,14 +78,26 @@ def test_local_mapreduce_compiles_at_block_shape(one_chip, name):
     assert 0 < _device_bytes(compiled) < HBM_BYTES
 
 
+def _sorts(hlo, stage):
+    """(result types, operands) of each sort of the program under `stage`."""
+    found = []
+    for line in hlo.splitlines():
+        if " sort(" in line and f"/{stage}/" in line:
+            result, operands = re.search(r"= (.*) sort\(([^)]*)\)",
+                                         line).groups()
+            found.append((re.findall(r"(\w+)\[\d+\]", result),
+                          operands.split(", ")))
+    return found
+
+
 @pytest.mark.parametrize("name", ["WC", "SC", "II", "Grep", "Permu"])
 def test_local_mapreduce_names_its_stages(one_chip, name):
-    """Every fusion, sort and scatter of the compiled program that JAX
-    emitted carries exactly one ``mr.*`` stage, and the right one; what XLA
-    makes itself (cumsum's reduce-window pieces) carries none. The sort
-    carries the values as its payload, so no computation of the program
-    gathers, and each ``mr.sort`` sorts the u32 keys with the s32 values,
-    not with an iota to gather by."""
+    """Every fusion and sort of the compiled program that JAX emitted
+    carries exactly one ``mr.*`` stage, and the right one; what XLA makes
+    itself (cumsum's reduce-window pieces) carries none. Nothing gathers or
+    scatters: each ``mr.sort`` sorts the u32 keys with the s32 values, not
+    with an iota to gather by, and ``mr.segment`` is one sort of the run
+    ends' u32 keys with their s32 prefix sums."""
     block = jax.ShapeDtypeStruct((1 << 12,), jnp.int32, sharding=one_chip)
     hlo = _local_mapreduce.lower(JOBS[name], block, block).compile(
         ).as_text()
@@ -102,23 +114,19 @@ def test_local_mapreduce_names_its_stages(one_chip, name):
             continue
         scopes = [p for p in op.group(1).split("/") if p.startswith("mr.")]
         assert len(scopes) == 1, line
-        last = op.group(1).rsplit("/", 1)[-1]
-        want = {"sort": "mr.sort",
-                "scatter": "mr.segment", "scatter-add": "mr.segment"}
-        assert scopes[0] == want.get(last, scopes[0]), line
+        if m.group(1) == "sort":
+            assert scopes[0] in ("mr.sort", "mr.segment"), line
         seen.add(scopes[0])
     assert seen == {"mr.map", "mr.sort", "mr.segment"}
-    assert " gather(" not in hlo
-    sorts = [line for line in hlo.splitlines()
-             if " sort(" in line and "/mr.sort/" in line]
-    assert sorts
-    for line in sorts:
-        result, operands = re.search(r"= (.*) sort\(([^)]*)\)",
-                                     line).groups()
-        assert re.findall(r"(\w+)\[\d+\]", result) == ["u32", "s32"], line
-        operands = operands.split(", ")
-        assert len(operands) == 2, line
-        assert not any(o.startswith("%iota") for o in operands), line
+    assert " gather(" not in hlo and " scatter(" not in hlo
+    passes = 2 if JOBS[name].combine_in_map else 1
+    for stage in ("mr.sort", "mr.segment"):
+        sorts = _sorts(hlo, stage)
+        assert len(sorts) == passes, (stage, sorts)
+        for result, operands in sorts:
+            assert result == ["u32", "s32"], (stage, result)
+            assert len(operands) == 2, (stage, operands)
+            assert not any(o.startswith("%iota") for o in operands), operands
 
 
 @pytest.mark.parametrize("shuffle", [("data",), ("pod", "data")])
@@ -146,3 +154,5 @@ def test_mesh_mapreduce_compiles_on_2x2(topo, shuffle):
             if re.search(r" (sort|scatter)\(", line)
             and "/mr.pack/" in line]
     assert "sort" in pack and "scatter" in pack, pack
+    scatters = [line for line in hlo.splitlines() if " scatter(" in line]
+    assert all("/mr.pack/" in line for line in scatters), scatters

@@ -66,6 +66,28 @@ def test_fp_stable_across_shards():
     assert float(np.std(fps)) < 0.15 * float(np.mean(fps))
 
 
+#: measure_fp of SC and II on 3 shards of 2048 tokens (corpus seeds 40-42),
+#: as the engine computed them with scatters in `_sort_reduce`
+FROZEN_FP = {
+    ("web", "II"): [1.1856039, 1.1868656, 1.1780405],
+    ("web", "SC"): [0.5986814, 0.6095714, 0.57467616],
+    ("non-web", "II"): [1.6170998, 1.6261082, 1.6320013],
+    ("non-web", "SC"): [3.2737477, 3.2318864, 3.2383893],
+}
+
+
+@pytest.mark.parametrize("kind, name", sorted(FROZEN_FP))
+def test_fp_is_frozen(kind, name):
+    """FP equals the frozen values exactly. SC's map-side combiner is the
+    one caller that keeps `_sort_reduce`'s combined bytes; II emits one
+    record per occurrence and stands beside it."""
+    shards = [corpus(kind, 2048, seed=s) for s in (40, 41, 42)]
+    fps = measure_fp(JOBS[name], np.stack([t for t, _ in shards]),
+                     np.stack([l for _, l in shards]))
+    np.testing.assert_array_equal(
+        fps, np.array(FROZEN_FP[kind, name], np.float32))
+
+
 def test_word_len_deterministic_and_typed():
     ids = np.array([1, 1, 70, 70, 200], np.int32)
     l1, l2 = word_len(ids), word_len(ids)
@@ -95,38 +117,57 @@ def test_local_mapreduce_matches_numpy_reference(name, kind):
 
 def _kv_slots(case, n=4096, seed=11):
     """Map-output slots as the map functions emit them: uint32 keys (EMPTY
-    slots carry value 0 and 0 bytes), one byte size per key."""
+    slots carry value 0 and 0 bytes), one byte size per key. ``wrap`` holds
+    values near 2**30, so the running sum and each key's own sum overflow
+    int32; the ``edges`` cases hold one valid slot, keys just below EMPTY
+    against the EMPTY run, and one key in every slot."""
     rng = np.random.default_rng(seed)
     if case == "all_empty":
         keys = np.full(n, EMPTY, np.uint32)
     elif case == "distinct":
         keys = rng.permutation(np.arange(n, dtype=np.uint32) * 977 + 5)
+    elif case == "edges-single":
+        keys = np.full(n, EMPTY, np.uint32)
+        keys[rng.integers(n)] = 977
+    elif case == "edges-below-empty":
+        keys = rng.choice(np.array([0, 5, EMPTY - 1, EMPTY], np.uint32), n)
+    elif case == "edges-one-key":
+        keys = np.full(n, 977, np.uint32)
     else:  # duplicates, EMPTY slots, and keys just below EMPTY
         pool = np.concatenate([rng.integers(0, 1 << 31, 200, np.uint32),
                                EMPTY - np.arange(1, 4, dtype=np.uint32)])
         keys = rng.choice(pool, n)
         keys[rng.random(n) < 0.3] = EMPTY
     valid = keys != EMPTY
-    values = np.where(valid, rng.integers(-5, 100, n), 0).astype(np.int32)
+    lo, hi = ((2**30 - 64, 2**30 + 64) if case == "wrap" else (-5, 100))
+    values = np.where(valid, rng.integers(lo, hi, n), 0).astype(np.int32)
     nbytes = np.where(valid, keys % 11 + 2, 0).astype(np.int32)
     return keys, values, nbytes
 
 
-@pytest.mark.parametrize("case", ["mixed", "distinct", "all_empty"])
+@pytest.mark.parametrize("case", [
+    "mixed", "distinct", "all_empty", "wrap", "edges-single",
+    "edges-below-empty", "edges-one-key"])
 @pytest.mark.parametrize("combined_bytes", [True, False])
 def test_sort_reduce_matches_numpy(case, combined_bytes):
     """Unique keys ascending, each key's summed values and its output bytes
-    (one representative kv, or the members' sum) equal numpy's exactly;
-    slots past n_unique hold EMPTY, 0 and 0."""
+    (one representative kv, or the members' sum) equal numpy's exactly,
+    int32 sums taken modulo 2**32; slots past n_unique hold EMPTY, 0 and
+    0."""
     keys, values, nbytes = _kv_slots(case)
     k, v, b, n = (np.asarray(a) for a in _sort_reduce(
         jnp.asarray(keys), jnp.asarray(values), jnp.asarray(nbytes),
         combined_bytes=combined_bytes))
     valid = keys != EMPTY
     want_k, inverse = np.unique(keys[valid], return_inverse=True)
-    want_v = np.bincount(inverse, values[valid], minlength=len(want_k))
-    want_b = (want_k % 11 + 2 if combined_bytes else
-              np.bincount(inverse, nbytes[valid], minlength=len(want_k)))
+
+    def sums(x):
+        total = np.zeros(len(want_k), np.int64)
+        np.add.at(total, inverse, x[valid])
+        return total.astype(np.int32)  # wraps modulo 2**32
+
+    want_v = sums(values)
+    want_b = want_k % 11 + 2 if combined_bytes else sums(nbytes)
     u = len(want_k)
     assert int(n) == u
     assert k.dtype == np.uint32 and v.dtype == b.dtype == np.int32
