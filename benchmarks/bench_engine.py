@@ -1,12 +1,8 @@
 """Beyond-paper: the JoSS reduce-placement insight measured on REAL jax
-collectives. Two experiments on an 8-device (2-pod x 4) host mesh:
-
-1. MapReduce shuffle scoping (policy A): shuffle over ('pod','data')
-   (off-pod) vs shuffle over ('data',) only (pod-local reduce), measured
-   as lowered-HLO collective wire bytes.
-2. Gradient reduction: flat all-reduce over both axes vs hierarchical
-   in-pod reduce-scatter + cross-pod all-reduce + in-pod all-gather
-   (sharding/collectives.py), also measured from the lowered HLO.
+collectives. MapReduce shuffle scoping (policy A) on an 8-device (2-pod
+x 4) host mesh: shuffle over ('pod','data') (off-pod) vs shuffle over
+('data',) only (pod-local reduce), measured as lowered-HLO collective
+wire bytes.
 
 Plus (PR 7, no devices needed): a per-event-kind timing profile of the
 discrete-event kernel itself — ``ProfilingKernel`` swapped in via the
@@ -54,30 +50,6 @@ def shuffle_scoping() -> list:
         t = analyze_hlo(txt, 8)
         a2a = t.per_collective.get("all-to-all", 0.0)
         rows.append([scope, a2a / 1024, t.collective_bytes / 1024])
-    return rows
-
-
-def grad_reduction() -> list:
-    from functools import partial
-    from jax.sharding import PartitionSpec as P
-    from repro.sharding.collectives import flat_psum, hierarchical_psum
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                         axis_types=(AxisType.Auto,) * 3)
-    g = jnp.zeros((1024, 64), jnp.float32)
-    rows = []
-    for name, fn in (("flat all-reduce", flat_psum),
-                     ("hierarchical (JoSS reduce placement)",
-                      hierarchical_psum)):
-        f = jax.shard_map(partial(fn, data_axis="data", pod_axis="pod"),
-                          mesh=mesh, in_specs=P(), out_specs=P(),
-                          check_vma=False)
-        txt = jax.jit(f).lower(g).compile().as_text()
-        t = analyze_hlo(txt, 8)
-        # pod-crossing bytes: collectives whose group spans pods use
-        # group size 8 (vs 2 for in-pod) — report total + breakdown
-        rows.append([name, t.collective_bytes / 1024,
-                     {k: round(v / 1024, 1)
-                      for k, v in t.per_collective.items()}])
     return rows
 
 
@@ -139,11 +111,6 @@ def run(quick: bool = False) -> str:
                      ["shuffle scope", "all-to-all KiB",
                       "total collective KiB"], rows))
     assert rows[1][2] <= rows[0][2], "pod-local shuffle must not move more"
-    rows = grad_reduction()
-    out.append(table("Gradient reduction: flat vs hierarchical "
-                     "(wire KiB, 8 devices)",
-                     ["schedule", "total KiB", "per-collective KiB"],
-                     rows))
     return "\n".join(out)
 
 

@@ -1,15 +1,10 @@
-"""Quickstart: the three layers of this framework in one minute.
+"""Quickstart: the two layers of this framework in one minute.
 
   1. JoSS itself — classify + place a MapReduce job on a virtual cluster.
   2. The simulator — JoSS-T vs Hadoop FIFO on a reduced paper workload.
-  3. The LM zoo — one training step of a reduced qwen3 config.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
-import jax
-import jax.numpy as jnp
-import numpy as np
-
 # ---------------------------------------------------------------- 1. JoSS
 from repro.core import Job, JossT, VirtualCluster
 from repro.core.topology import HostId
@@ -34,17 +29,3 @@ res = run_comparison("small", n_jobs=20, algos=("joss-t", "fifo"))
 for name, s in res.items():
     print(f"[2] {name:7s} inter-pod traffic = {s.int_mb:8.0f} MB, "
           f"WC off-pod map rate = {s.map_locality['WC'].off_cen:.2f}")
-
-# ------------------------------------------------------------- 3. LM zoo
-from repro.configs import get_config
-from repro.models import build_model
-from repro.train import TrainConfig, adamw_init, make_train_step
-
-cfg = get_config("qwen3-4b").smoke()      # reduced same-family config
-model = build_model(cfg)
-params = model.init(jax.random.PRNGKey(0))
-step = jax.jit(make_train_step(model, TrainConfig()))
-batch = {"tokens": jnp.asarray(
-    np.random.RandomState(0).randint(0, cfg.vocab, (4, 64)), jnp.int32)}
-params, opt_state, metrics = step(params, adamw_init(params), batch)
-print(f"[3] qwen3 (smoke) train step: loss = {float(metrics['loss']):.3f}")

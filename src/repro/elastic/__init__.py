@@ -6,9 +6,6 @@ this package makes the fleet mutable: leases and rental-cost accounting
 preemptions / lease expiries (``churn``), autoscaler policies driven by
 the PR 1 backlog counters (``autoscaler``), and the engine that glues
 them to the discrete-event simulator (``engine``).
-
-(``repro.runtime.elastic`` remains the training-side re-meshing planner;
-this package is the scheduling/simulation side.)
 """
 from repro.elastic.autoscaler import (Autoscaler, BacklogThresholdScaler,
                                       CompactingScaler,

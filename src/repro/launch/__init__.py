@@ -1,8 +1,2 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve drivers.
-
-NOTE: do not import repro.launch.dryrun from library code — it sets
-XLA_FLAGS at import time (by design: it must run before jax initializes).
-"""
-from repro.launch.mesh import make_host_mesh, make_production_mesh
-
-__all__ = ["make_host_mesh", "make_production_mesh"]
+"""HLO text analysis: per-device FLOPs, memory bytes and collective wire
+bytes of a compiled program (``hlo_analysis``)."""

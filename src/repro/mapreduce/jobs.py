@@ -92,7 +92,8 @@ def sc_map(tokens, lengths, doc_id) -> KVBatch:
 
 def ii_map(tokens, lengths, doc_id) -> KVBatch:
     valid = tokens >= 0
-    return _emit(tokens, jnp.full_like(tokens, doc_id), lengths + 4, valid)
+    return _emit(tokens, jnp.broadcast_to(doc_id, tokens.shape), lengths + 4,
+                 valid)
 
 
 def grep_map_factory(pattern_id: int):

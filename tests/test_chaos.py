@@ -81,7 +81,7 @@ def test_empty_campaign_is_empty():
 
 
 # --------------------------------------------- golden bit-identity (off) --
-@pytest.mark.parametrize("algo,variant", golden_cases()[::5])
+@pytest.mark.parametrize("algo,variant", golden_cases())
 def test_calm_attached_layer_is_bit_identical_to_golden(algo, variant):
     """An attached chaos layer with an empty campaign plus an inert
     detector must not move a single event vs the committed goldens —

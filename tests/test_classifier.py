@@ -8,6 +8,8 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import (FpRegistry, Job, JobClassifier, JobKind,
                         VirtualCluster, classify_input_type)
+from repro.core.classifier import best_threshold
+from repro.sim.workloads import BLOCK_MB, PAPER_BENCHMARKS
 
 
 def mk_job(m, fp=1.0, name="j", input_type="web"):
@@ -86,3 +88,34 @@ def test_input_type_classifier():
     assert classify_input_type(web) == "web"
     assert classify_input_type(txt) == "non-web"
     assert classify_input_type("") == "non-web"
+
+
+@pytest.mark.parametrize("k", [2, 3])
+@pytest.mark.parametrize("gb", [1, 12])
+@pytest.mark.parametrize("name", sorted(PAPER_BENCHMARKS))
+def test_paper_jobs_classify_as_eq3_and_eq4_say(name, gb, k):
+    """Each Table 5 job at a Table 7 size (1 GB: 8 maps, 12 GB: 96) on k
+    pods of 15 VPSs. Eq. 4: small iff m <= N_avg_VPS. Eq. 3: RH iff the
+    policy-B worst case TR2 = (k-1)/k * S_map * FP (Eq. 6) exceeds the
+    policy-A worst case TR1 = S_map (Eq. 5), i.e. FP > td = k/(k-1)."""
+    bench = PAPER_BENCHMARKS[name]
+    m = int(gb * 1024 / BLOCK_MB)
+    assert m == {1: 8, 12: 96}[gb]
+    vps = [15] * k
+    cluster = VirtualCluster(vps)
+    reg = FpRegistry()
+    clf = JobClassifier(cluster, reg)
+    job = Job(name=name, code_key=name, input_type=bench.input_type,
+              shard_ids=[f"{name}/{gb}/B{i}" for i in range(m)],
+              shard_bytes=[BLOCK_MB] * m, true_fp=bench.fp)
+    small = m <= sum(vps) / k
+    s_map = job.s_map
+    rh = (k - 1) / k * s_map * bench.fp > s_map
+    assert best_threshold(k) == k / (k - 1)
+    assert rh == (bench.fp > best_threshold(k))
+    # first sighting: a small job goes through the FIFO queues unprofiled
+    assert clf.classify(job) is (JobKind.UNKNOWN if small else JobKind.LARGE)
+    reg.record(job, bench.fp)
+    want = (JobKind.LARGE if not small
+            else JobKind.SMALL_RH if rh else JobKind.SMALL_MH)
+    assert clf.classify(job) is want

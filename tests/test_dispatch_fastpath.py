@@ -16,8 +16,8 @@ from repro.core.reference import (ReferenceJTA, ReferenceTTA,
                                   reference_fifo_pick_map)
 from repro.core.topology import HostId, VirtualCluster
 from repro.sim.cluster_sim import SimConfig, Simulator
-from repro.sim.workloads import (make_cluster, profiling_prelude,
-                                 small_workload)
+from repro.sim.workloads import (make_cluster, mixed_workload,
+                                 profiling_prelude, small_workload)
 
 ALGOS = ("joss-t", "joss-j", "fifo", "fair", "capacity")
 
@@ -82,16 +82,21 @@ def test_simulation_equivalence_randomized(name, seed):
     assert fast_seq == ref_seq
 
 
-@pytest.mark.parametrize("name", ("joss-t", "joss-j"))
-def test_simulation_equivalence_paper_workload(name):
-    """Same check on the paper's small workload (policies A/B/C mix)."""
+@pytest.mark.parametrize("workload", ("small", "mixed"))
+@pytest.mark.parametrize("name", ALGOS)
+def test_simulation_equivalence_paper_workload(name, workload):
+    """Same check on the paper's workloads: Table 6's small jobs and
+    Table 7's mix of 1, 5 and 12 GB jobs (policies A/B/C). JoSS is
+    profiled first; the baselines keep no FP registry."""
     def run(factory):
         cluster = make_cluster((4, 4))
-        jobs = small_workload(cluster, seed=5, n_jobs=12)
+        jobs = (small_workload(cluster, seed=5, n_jobs=12)
+                if workload == "small" else mixed_workload(cluster, seed=5))
         idx = {j.job_id: i for i, j in enumerate(jobs)}
         algo = factory(name, cluster)
-        for j in profiling_prelude(cluster):
-            algo.registry.record(j, j.true_fp)
+        if hasattr(algo, "registry"):
+            for j in profiling_prelude(cluster):
+                algo.registry.record(j, j.true_fp)
         res = Simulator(cluster, algo, jobs, seed=5).run()
         return (res.wtt, res.int_bytes, res.pod_bytes,
                 [((log.task.tid[0], idx[log.task.tid[1]],

@@ -1,4 +1,8 @@
 """§4.2 scheduling policies, including the paper's Fig. 3 worked example."""
+import random
+
+import pytest
+
 from repro.core import (Job, VirtualCluster, policy_a, policy_b, policy_c)
 from repro.core.queues import ClusterQueues
 from repro.core.topology import HostId
@@ -67,3 +71,88 @@ def test_policy_b_replica_less_shard_falls_back():
     assert len(plan.map_assignment) == 2
     assert plan.map_assignment[0] == 0      # replica-backed
     assert plan.map_assignment[1] in (0, 1)  # fallback is deterministic
+
+
+# -- policies A/B/C against an enumeration oracle -----------------------------
+#: unprocessed tasks queued at pod c: pod 0 has the fewest, so policy A
+#: must skip it when it is hostless; pods 2 and 4 tie
+POD_LOAD = [0, 3, 1, 2, 1]
+
+
+def _layout_cluster(k, replication, layout):
+    """k pods of 2-3 hosts, 2k+3 shards with ``replication`` replicas on
+    distinct random hosts. ``hostless``: every host of pod 0 is returned
+    (its replicas go with it). ``lost``: the job also reads a shard that
+    was never placed, and one placed shard loses every replica to host
+    departures whose pods are then refilled with fresh hosts."""
+    rng = random.Random(100 * k + 10 * replication + len(layout))
+    cluster = VirtualCluster([2 + c % 2 for c in range(k)])
+    hosts = [h.hid for h in cluster.hosts()]
+    sids = [f"B{i}" for i in range(2 * k + 3)]
+    for s in sids:
+        cluster.place_shard(s, rng.sample(hosts, replication))
+    if layout == "hostless":
+        for h in list(cluster.pods[0].hosts):
+            cluster.remove_host(h.hid)
+    elif layout == "lost":
+        for hid in list(cluster.shard_replicas["B1"]):
+            if cluster.has_host(hid):
+                cluster.remove_host(hid)
+                cluster.add_host(hid.pod)
+        sids.append("never-placed")
+    job = Job(name="P", code_key="P", input_type="web", shard_ids=sids,
+              shard_bytes=[128.0] * len(sids), n_reducers=2)
+    return cluster, job
+
+
+def _oracle_reduce_pod(cluster, job):
+    """The live pod holding the most unique shards of the job, counted
+    from the replica host lists; ties to the lowest pod id."""
+    held = {c: 0 for c in range(cluster.k) if cluster.pods[c].hosts}
+    for s in set(job.shard_ids):
+        for c in {h.pod for h in cluster.shard_replicas.get(s, ())}:
+            held[c] += 1
+    best = max(held.values())
+    return min(c for c, n in held.items() if n == best)
+
+
+@pytest.mark.parametrize("layout", ["healthy", "hostless", "lost"])
+@pytest.mark.parametrize("replication", [1, 2, 3])
+@pytest.mark.parametrize("k", [2, 3, 4, 5])
+def test_policies_match_the_enumeration_oracle(k, replication, layout):
+    cluster, job = _layout_cluster(k, replication, layout)
+    queues = ClusterQueues(cluster)
+    for c in range(k):
+        queues.pods[c].mq0.extend([object()] * POD_LOAD[c])
+    live = [c for c in range(k) if cluster.pods[c].hosts]
+    want_a = min(live, key=lambda c: (queues.pods[c].unprocessed(), c))
+    want_reduce = _oracle_reduce_pod(cluster, job)
+    assert layout != "hostless" or 0 not in (want_a, want_reduce)
+
+    a = policy_a(job, cluster, queues)
+    assert queues.least_loaded_pod() == want_a
+    assert a.map_assignment == [want_a] * job.m
+    assert a.reduce_pod == want_a and a.policy == "A"
+
+    b = policy_b(job, cluster, queues)
+    c = policy_c(job, cluster, queues)
+    assert (b.map_assignment, b.reduce_pod) == (c.map_assignment,
+                                                c.reduce_pod)
+    assert (a.new_queues, b.new_queues, c.new_queues) == (False, False, True)
+    assert b.reduce_pod == want_reduce
+    lost = set()
+    for sid, pod in zip(job.shard_ids, b.map_assignment):
+        pods = {h.pod for h in cluster.shard_replicas.get(sid, ())}
+        if pods:
+            assert pod in pods, (sid, pod, pods)
+        else:
+            lost.add(sid)
+            assert pod == want_reduce, (sid, pod)
+        # the first largest set is the reduce pod's: it keeps its shards
+        if want_reduce in pods:
+            assert pod == want_reduce, (sid, pod)
+        assert cluster.pods[pod].hosts, (sid, pod)
+    if layout == "healthy":
+        assert not lost
+    elif layout == "lost":
+        assert {"B1", "never-placed"} <= lost

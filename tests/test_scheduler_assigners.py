@@ -1,5 +1,7 @@
 """Fig. 4 task scheduler + Fig. 5/6 assigners: FIFO-first profiling path,
 round-robin over queues (starvation avoidance), TTA vs JTA semantics."""
+import pytest
+
 from repro.core import (FpRegistry, Job, JobKind, JossScheduler, JossT,
                         JossJ, TaskState, VirtualCluster)
 from repro.core.topology import HostId
@@ -96,3 +98,80 @@ def test_reduce_task_gating():
     pod = algo.plan_of(j).reduce_pod
     assert algo.next_reduce_task(HostId(pod, 0), ready_no) is None
     assert algo.next_reduce_task(HostId(pod, 0), ready_yes) is not None
+
+
+#: map situations of a host asking for work, on two pods of 4 VPSs: the
+#: replica host of each of the job's shards (None: never placed), the
+#: job's FP, and the offers in turn as (host, TTA's pick, JTA's pick),
+#: a pick being the map task's index or None (no task given)
+MAP_SITUATIONS = {
+    # two shards on the asking host behind two pod-local ones (policy B)
+    "host_local": ([(0, 1), (0, 2), (0, 0), (0, 0)], 1.0,
+                   [((0, 0), 0, 2), ((0, 0), 1, 3), ((0, 0), 2, None),
+                    ((0, 0), 3, 0)]),
+    # a replica-less head task (sent to the reduce pod) before two
+    # pod-local ones: JTA defers once per task, pod-local first
+    "pod_local": ([None, (0, 1), (0, 2)], 1.0,
+                  [((0, 0), 0, None), ((0, 0), 1, 1), ((0, 0), 2, None),
+                   ((0, 0), None, 2), ((0, 0), None, None),
+                   ((0, 0), None, 0)]),
+    # reduce-heavy (policy A): pinned to the least-loaded pod 0 while
+    # every shard lives in pod 1, whose hosts get nothing
+    "remote": ([(1, 0), (1, 1)], 3.0,
+               [((1, 0), None, None), ((0, 0), 0, None), ((0, 0), 1, 0),
+                ((0, 0), None, None), ((0, 0), None, 1)]),
+}
+
+
+def placed_job(cluster, name, replicas, fp):
+    sids = [f"{name}/B{i}" for i in range(len(replicas))]
+    for s, rep in zip(sids, replicas):
+        if rep is not None:
+            cluster.place_shard(s, [HostId(*rep)])
+    return Job(name=name, code_key=name, input_type="web", shard_ids=sids,
+               shard_bytes=[128.0] * len(sids), true_fp=fp)
+
+
+@pytest.mark.parametrize("situation", [*MAP_SITUATIONS, "reduce_ready"])
+@pytest.mark.parametrize("algo_cls", [JossT, JossJ])
+def test_assigner_serves_a_host_in_the_papers_order(algo_cls, situation):
+    """Fig. 5 (TTA) takes the head of the queue at once; Fig. 6 (JTA)
+    takes the head job's host-local task, else defers the host one
+    heartbeat before a pod-local task, else before the head task. Both
+    give the first ready reduce task of the pod's queue in queue order."""
+    c = cluster2(4)
+    algo = algo_cls(c)
+    if situation == "reduce_ready":
+        first, second = (placed_job(c, f"r{i}-{algo.name}", [(1, i)], 1.0)
+                         for i in range(2))
+        for j in (first, second):
+            algo.registry.record(j, 1.0)
+            algo.submit(j)
+            assert algo.plan_of(j).reduce_pod == 1
+        ready = set()
+
+        def pick(host):
+            t = algo.next_reduce_task(HostId(*host),
+                                      lambda t: t.job_id in ready)
+            return None if t is None else t.job_id
+
+        assert pick((1, 0)) is None
+        ready.add(second.job_id)
+        assert pick((1, 0)) == second.job_id
+        ready.add(first.job_id)
+        assert pick((0, 0)) is None          # reduces stay in pod 1
+        assert pick((1, 0)) == first.job_id
+        assert pick((1, 0)) is None
+        return
+    replicas, fp, offers = MAP_SITUATIONS[situation]
+    job = placed_job(c, f"{situation}-{algo.name}", replicas, fp)
+    algo.registry.record(job, fp)
+    algo.submit(job)
+    assert algo.plan_of(job).policy == ("A" if fp > 2 else "B")
+    got, want = [], []
+    for host, tta, jta in offers:
+        t = algo.next_map_task(HostId(*host))
+        assert t is None or t.job_id == job.job_id
+        got.append(None if t is None else t.index)
+        want.append(tta if algo_cls is JossT else jta)
+    assert got == want
