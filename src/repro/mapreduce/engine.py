@@ -14,6 +14,11 @@ Both paths name their stages for the profiler with `jax.named_scope`
 is one jitted program, `_mesh_mapreduce`, keyed on (spec, mesh, axes,
 slack). `local_mapreduce` and `mesh_mapreduce` open the host span
 `mr.dispatch` around their dispatch.
+
+The pack is one sort by destination that carries keys and values, then one
+contiguous slice per destination: no gather, no scatter, no spill slot.
+Records past a destination's buffer are counted (`dropped`) and lost;
+which of them an over-full destination keeps is unspecified.
 """
 from __future__ import annotations
 
@@ -130,25 +135,35 @@ def measure_fp(spec: MapReduceSpec, shards_tokens: np.ndarray,
 def _partition_pack(kv: KVBatch, n_dest: int, cap_dest: int):
     """Bucket kv records by destination = key % n_dest into fixed-size
     per-destination buffers (EMPTY-padded); returns (keys, vals) shaped
-    (n_dest, cap_dest) plus the number of dropped (overflow) records."""
+    (n_dest, cap_dest) plus the number of dropped (overflow) records.
+
+    One unstable sort by destination carries the keys and values, so
+    nothing gathers by a permutation; EMPTY records sort last. Row j is
+    then one contiguous slice of cap_dest slots at destination j's start
+    (the exclusive prefix sum of the per-destination counts), its slots
+    past j's count set to EMPTY/0, so nothing scatters either. Records
+    past cap_dest in an over-full destination are counted in `dropped`
+    and lost; which of its records the row keeps is unspecified.
+    """
     dest = jnp.where(kv.keys == EMPTY, jnp.uint32(n_dest), kv.keys % n_dest)
-    order = jnp.argsort(dest)
-    d = dest[order]
-    k = kv.keys[order]
-    v = kv.values[order]
-    # rank of each record within its destination bucket
-    starts = jnp.searchsorted(d, jnp.arange(n_dest + 1, dtype=d.dtype))
-    rank = jnp.arange(d.shape[0]) - starts[jnp.clip(d, 0, n_dest)]
-    ok = (d < n_dest) & (rank < cap_dest)
-    slot = jnp.clip(d.astype(jnp.int32), 0, n_dest - 1) * cap_dest + rank
-    slot = jnp.where(ok, slot, n_dest * cap_dest)  # spill slot
-    buf_k = jnp.full((n_dest * cap_dest + 1,), EMPTY, jnp.uint32)
-    buf_v = jnp.zeros((n_dest * cap_dest + 1,), jnp.int32)
-    buf_k = buf_k.at[slot].set(k)
-    buf_v = buf_v.at[slot].set(v)
-    dropped = jnp.sum((d < n_dest) & ~ok)
-    return (buf_k[:-1].reshape(n_dest, cap_dest),
-            buf_v[:-1].reshape(n_dest, cap_dest), dropped)
+    _, k, v = jax.lax.sort((dest, kv.keys, kv.values), num_keys=1,
+                           is_stable=False)
+    counts = jnp.sum(dest == jnp.arange(n_dest, dtype=dest.dtype)[:, None],
+                     axis=1, dtype=jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    # cap_dest slots of padding, so that no slice is clamped
+    k = jnp.concatenate([k, jnp.full((cap_dest,), EMPTY, k.dtype)])
+    v = jnp.concatenate([v, jnp.zeros((cap_dest,), v.dtype)])
+    slot = jnp.arange(cap_dest)
+
+    def rows(x, fill):
+        return jnp.stack([
+            jnp.where(slot < counts[j],
+                      jax.lax.dynamic_slice(x, (starts[j],), (cap_dest,)),
+                      fill)
+            for j in range(n_dest)])
+    dropped = jnp.sum(jnp.maximum(counts - cap_dest, 0))
+    return rows(k, EMPTY), rows(v, 0), dropped
 
 
 def mesh_mapreduce(spec: MapReduceSpec, tokens, lengths, mesh: Mesh,

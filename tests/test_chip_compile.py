@@ -133,8 +133,10 @@ def test_local_mapreduce_names_its_stages(one_chip, name):
 def test_mesh_mapreduce_compiles_on_2x2(topo, shuffle):
     """The benchmark's 1 GiB WordCount job, 2 blocks of 20 Mi slots per
     chip, fits one chip's HBM as the one jitted mesh program; every
-    all-to-all carries ``mr.shuffle``, and the pack's sort and scatters
-    carry ``mr.pack``."""
+    all-to-all carries ``mr.shuffle``. Nothing in the program gathers or
+    scatters: the pack is exactly one sort carrying ``mr.pack`` (by
+    destination, with the keys and values), then a slice per
+    destination."""
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("pod", "data"),
                 axis_types=(AxisType.Auto,) * 2)
     blocks = jax.ShapeDtypeStruct(
@@ -149,10 +151,7 @@ def test_mesh_mapreduce_compiles_on_2x2(topo, shuffle):
            if re.search(r" all-to-all(-start)?\(", line)]
     assert len(a2a) == 2
     assert all("/mr.shuffle/" in line for line in a2a), a2a
-    pack = [re.search(r" (sort|scatter)\(", line).group(1)
-            for line in hlo.splitlines()
-            if re.search(r" (sort|scatter)\(", line)
-            and "/mr.pack/" in line]
-    assert "sort" in pack and "scatter" in pack, pack
-    scatters = [line for line in hlo.splitlines() if " scatter(" in line]
-    assert all("/mr.pack/" in line for line in scatters), scatters
+    assert " gather(" not in hlo and " scatter(" not in hlo
+    pack = _sorts(hlo, "mr.pack")
+    assert len(pack) == 1, pack
+    assert pack[0][0] == ["u32", "u32", "s32"], pack

@@ -7,8 +7,8 @@ import numpy as np
 import pytest
 
 from repro.mapreduce import JOBS, corpus, local_mapreduce, measure_fp
-from repro.mapreduce.engine import _sort_reduce
-from repro.mapreduce.jobs import EMPTY, block, word_len
+from repro.mapreduce.engine import _partition_pack, _sort_reduce
+from repro.mapreduce.jobs import EMPTY, KVBatch, block, word_len
 from repro.mapreduce.reference import emission, reduce_counts
 
 
@@ -176,6 +176,61 @@ def test_sort_reduce_matches_numpy(case, combined_bytes):
     np.testing.assert_array_equal(b[:u], want_b)
     assert np.all(k[u:] == EMPTY) and np.all(v[u:] == 0)
     assert np.all(b[u:] == 0)
+
+
+def _pack_records(case, n_dest, n=1024):
+    """(keys, values, cap_dest) for a `_partition_pack` case: about a
+    fifth of the slots EMPTY, the rest random keys below 2**31."""
+    rng = np.random.default_rng(7 * n_dest + len(case))
+    keys = rng.integers(0, 1 << 31, n, np.uint32)
+    if case == "overflow":  # ~70% of the keys go to destination 0
+        keys = np.where(rng.random(n) < 0.7, keys // n_dest * n_dest, keys)
+    if case == "one_dest_empty":  # nothing goes to destination 1
+        keys = np.where(keys % n_dest == 1, keys + 1, keys)
+    keys[rng.random(n) < 0.2] = EMPTY
+    if case == "all_empty":
+        keys[:] = EMPTY
+    values = np.where(keys != EMPTY, rng.integers(-50, 1 << 20, n),
+                      0).astype(np.int32)
+    return keys.astype(np.uint32), values, (
+        n if case == "one_dest_empty" else n // 2)
+
+
+@pytest.mark.parametrize("case", ["no_overflow", "overflow", "all_empty",
+                                  "one_dest_empty"])
+@pytest.mark.parametrize("n_dest", [2, 4])
+def test_partition_pack_matches_numpy(case, n_dest):
+    """Row j holds (key, value) records of destination ``key % n_dest ==
+    j`` only, then EMPTY/0: all of them when they fit, exactly
+    ``cap_dest`` of them when they do not; ``dropped`` is the records
+    past ``cap_dest`` summed over destinations."""
+    keys, values, cap_dest = _pack_records(case, n_dest)
+    kv = KVBatch(jnp.asarray(keys), jnp.asarray(values),
+                 jnp.zeros(len(keys), jnp.int32), len(keys))
+    bk, bv, dropped = (np.asarray(a)
+                       for a in _partition_pack(kv, n_dest, cap_dest))
+    assert bk.shape == bv.shape == (n_dest, cap_dest)
+    assert bk.dtype == np.uint32 and bv.dtype == np.int32
+    valid = keys != EMPTY
+    counts = np.bincount(keys[valid] % n_dest, minlength=n_dest)
+    over = counts > cap_dest
+    assert over.sum() == (case == "overflow"), counts
+    if case == "one_dest_empty":
+        assert counts[1] == 0 and counts.sum() > 0
+    assert int(dropped) == int(np.maximum(counts - cap_dest, 0).sum())
+    for j in range(n_dest):
+        m = int(min(counts[j], cap_dest))
+        assert np.all(bk[j, m:] == EMPTY) and np.all(bv[j, m:] == 0)
+        row = collections.Counter(zip(bk[j, :m].tolist(),
+                                      bv[j, :m].tolist()))
+        mine = valid & (keys % n_dest == j)
+        want = collections.Counter(zip(keys[mine].tolist(),
+                                       values[mine].tolist()))
+        assert sum(row.values()) == m
+        if over[j]:
+            assert not row - want, j
+        else:
+            assert row == want, j
 
 
 def test_block_cuts_at_byte_budget_and_pads():
